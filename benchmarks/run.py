@@ -262,6 +262,8 @@ def main() -> None:
         write_report(args.report_tolerance)
         return
     _ensure_src_importable()
+    from repro.launch import compile_cache
+    compile_cache.enable()
     suite = _suite()
     if args.list:
         for name in sorted(suite):

@@ -274,18 +274,11 @@ def _one_step(cfg: BoostConfig, cls, x, y, x_orders, sched,
     success = (~stuck) & (out.t >= bound)
     ended = stuck | success
     k_alive = jnp.sum(pa.astype(jnp.int32))
-    # ---- full-point quarantine, masked to the round's senders ---------
-    core_flat = out.core_x.reshape((-1,) + out.core_x.shape[2:])
-    valid_flat = jnp.repeat(pa, cfg.coreset_size)
-    masked_flat = classify.mask_invalid_points(core_flat, valid_flat)
-    dead_new = s.alive & classify.match_points(x, masked_flat) & stuck
-    p_count = jnp.where(
-        stuck, classify.distinct_count_masked(core_flat, valid_flat), 0)
     nxt = StepState(
         attempt=jnp.where(ended, a + 1, a),
         done=s.done | success,
-        alive=s.alive & ~dead_new,
-        disputed=s.disputed | dead_new,
+        alive=s.alive,
+        disputed=s.disputed,
         key_data=key_data,
         h_params=jnp.where(success, out.h_params, s.h_params),
         rounds=jnp.where(success, out.t, s.rounds),
@@ -295,7 +288,7 @@ def _one_step(cfg: BoostConfig, cls, x, y, x_orders, sched,
         hist_rounds=jnp.where(ended, s.hist_rounds.at[a].set(out.t),
                               s.hist_rounds),
         hist_alive=hist_alive,
-        hist_p=jnp.where(ended, s.hist_p.at[a].set(p_count), s.hist_p),
+        hist_p=s.hist_p,
         hist_players=s.hist_players.at[a].add(k_alive),
         hist_players_h=s.hist_players_h.at[a].add(
             jnp.where(stuck, 0, k_alive)),
@@ -309,8 +302,44 @@ def _one_step(cfg: BoostConfig, cls, x, y, x_orders, sched,
         core_x=out.core_x, core_y=out.core_y,
         step=s.step + 1)
     # finished lanes freeze (vmap-of-while masking)
-    return jax.tree_util.tree_map(
+    nxt = jax.tree_util.tree_map(
         lambda new, old: jnp.where(active, new, old), nxt, s)
+    return nxt, Stuck(stuck & active, out.core_x, pa)
+
+
+class Stuck(NamedTuple):
+    """What a lane's quarantine needs from the round it stuck in."""
+    stuck: jax.Array       # the round stuck (and the lane was active)
+    core_x: jax.Array      # [k, c(, F)] the pooled coreset
+    senders: jax.Array     # [k] the players who sent it
+
+
+def quarantine(cfg: BoostConfig, x, alive, st: Stuck):
+    """Full-point quarantine of one lane's stuck round, masked to the
+    round's senders: (dead_new [k, mloc], P = distinct points disputed).
+
+    LOCKSTEP with the sharded engine, which calls it on its shard.
+    The step loops run it only on rounds where some lane stuck
+    (``lax.cond`` over the whole batch): under ``vmap`` a per-lane cond
+    would run on every round, and matching every point against the k·c
+    coreset points — then counting the distinct ones — is per-point
+    work no other round needs."""
+    core_flat = st.core_x.reshape((-1,) + st.core_x.shape[2:])
+    valid_flat = jnp.repeat(st.senders, cfg.coreset_size)
+    masked_flat = classify.mask_invalid_points(core_flat, valid_flat)
+    dead_new = alive & classify.match_points(x, masked_flat) & st.stuck
+    p_count = jnp.where(
+        st.stuck, classify.distinct_count_masked(core_flat, valid_flat), 0)
+    return dead_new, p_count
+
+
+def _quarantine_lane(cfg: BoostConfig, x, s: StepState,
+                     st: Stuck) -> StepState:
+    dead_new, p_count = quarantine(cfg, x, s.alive, st)
+    return s._replace(
+        alive=s.alive & ~dead_new, disputed=s.disputed | dead_new,
+        hist_p=jnp.where(st.stuck, s.hist_p.at[s.attempt - 1].set(p_count),
+                         s.hist_p))
 
 
 def _run_steps(x, y, sched, state: StepState, n, cfg: BoostConfig,
@@ -332,8 +361,13 @@ def _run_steps(x, y, sched, state: StepState, n, cfg: BoostConfig,
 
     def body(carry):
         s, i = carry
-        s2 = jax.vmap(functools.partial(_one_step, cfg, cls))(
+        s2, st = jax.vmap(functools.partial(_one_step, cfg, cls))(
             x, y, x_orders, sched, s)
+        s2 = jax.lax.cond(
+            jnp.any(st.stuck),
+            lambda s2: jax.vmap(functools.partial(_quarantine_lane, cfg))(
+                x, s2, st),
+            lambda s2: s2, s2)
         return s2, i + 1
 
     out, _ = jax.lax.while_loop(cond, body, (state, jnp.int32(0)))

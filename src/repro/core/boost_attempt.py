@@ -31,20 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-# jax.shard_map (with check_vma) landed after 0.4.x; fall back to the
-# experimental entry point (check_rep) so the sharded form runs on the
-# pinned toolchain as well as newer jax.
-if hasattr(jax, "shard_map"):
-    def _shard_map(f, mesh, in_specs, out_specs):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-else:
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def _shard_map(f, mesh, in_specs, out_specs):
-        return _exp_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=False)
-
 from repro.core import approximation, weights as W
 from repro.core import streaming, weak
 from repro.core.types import BoostAttemptResult, BoostConfig
@@ -83,13 +69,14 @@ def _center_erm(cls, cx, cy, mix, c):
     sharded engine's real collectives produce (bit-parity per mode).
     """
     k = cy.shape[0]
+    pw = W.erm_weights(mix, c)
     # jax.named_scope is device-side metadata (it adds no ops and no
     # host work) — profiler traces group the ERM under this label; it
     # is NOT an obs emission, so RL006 permits it in traced code
     with jax.named_scope("center_erm"):
         if getattr(cls, "comm_mode", "coreset") != "coreset":
-            return cls.erm_players(cx, cy, mix / c)
-        w = jnp.broadcast_to(mix[:, None] / c, (k, c)).reshape(-1)
+            return cls.erm_players(cx, cy, pw)
+        w = jnp.broadcast_to(pw[:, None], (k, c)).reshape(-1)
         cx_flat = cx.reshape((k * c,) + cx.shape[2:])
         cy_flat = cy.reshape(-1)
         return cls.erm(cx_flat, cy_flat, w)
@@ -320,5 +307,5 @@ def boost_attempt_sharded(mesh, cfg: BoostConfig, cls, num_rounds: int,
 
     in_specs = (P(*axes), P(*axes), P(*axes), P(*axes), P())
     out_specs = (P(), P(), P(*axes), P(), P())
-    return _shard_map(per_device, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs)
+    return jax.shard_map(per_device, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

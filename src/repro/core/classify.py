@@ -47,13 +47,62 @@ from repro.obs import trace as obs_trace
 # ---------------------------------------------------------------------------
 # Array-form quarantine primitives (jit-safe; used by core/batched.py).
 #
-# The host loop below dedupes the stuck coreset with np.unique/np.isin;
+# The host loop below dedupes the stuck coreset with np.unique;
 # on device the same semantics are masked point-matching: an example
 # dies iff its point equals ANY entry of the stuck coreset, and the
-# dispute-table size P is the number of distinct coreset values.  Both
-# are O(m·K) / O(K²) compares with K = k·coreset_size — small, fixed
-# shapes, no data-dependent output size.
+# dispute-table size P is the number of distinct coreset values.
+# Matching m points against P is a sort of the P and a binary search
+# per point, O((m+P)·log P): the [m, P] compare it replaced held 8.6 GB
+# at B = 8, m = 2^17, P = k·coreset_size = 8192 — more than half a v5e.
 # ---------------------------------------------------------------------------
+
+def _sort_order(pts: jax.Array) -> jax.Array:
+    """Stable sort order of points, rows lexicographically (feature 0
+    major).  JAX sorts −0.0 equal to +0.0 and every NaN last, so the
+    order agrees with ``==`` and with :func:`_search`'s comparison.
+
+    Rows take one stable single-key sort per feature, last feature
+    first, in a loop: the TPU compiler did not finish a 28-key sort
+    (``jnp.lexsort``) in minutes, and compiles this loop's one sort in
+    seconds for any F."""
+    if pts.ndim == 1:
+        return jnp.argsort(pts, stable=True)
+    F = pts.shape[1]
+
+    def by_feature(i, order):
+        key = jnp.take(pts[order], F - 1 - i, axis=1)
+        return order[jnp.argsort(key, stable=True)]
+
+    return jax.lax.fori_loop(0, F, by_feature,
+                             jnp.arange(pts.shape[0], dtype=jnp.int32))
+
+
+def _equal(a: jax.Array, b: jax.Array) -> jax.Array:
+    return a == b if a.ndim == 1 else jnp.all(a == b, axis=-1)
+
+
+def _search(ps: jax.Array, q: jax.Array, side: str) -> jax.Array:
+    """``searchsorted`` of q [M] / [M, F] into sorted ps [P] / [P, F]."""
+    if ps.ndim == 1:
+        return jnp.searchsorted(ps, q, side=side).astype(jnp.int32)
+
+    def less(a, b):                              # lexicographic a < b
+        ne = (a != b).astype(jnp.int32)
+        tied_before = jnp.cumsum(ne, axis=-1) - ne == 0
+        return jnp.any((a < b) & tied_before, axis=-1)
+
+    P = ps.shape[0]
+    lo = jnp.zeros(q.shape[:1], jnp.int32)
+    hi = jnp.full(q.shape[:1], P, jnp.int32)
+    for _ in range(P.bit_length()):
+        mid = (lo + hi) // 2
+        p = ps[jnp.minimum(mid, P - 1)]
+        below = less(p, q) if side == "left" else less(p, q) | _equal(p, q)
+        right = (lo < hi) & below
+        lo = jnp.where(right, mid + 1, lo)
+        hi = jnp.where(right, hi, mid)
+    return lo
+
 
 def match_points(x: jax.Array, pts: jax.Array) -> jax.Array:
     """alive-agnostic point match: out[...] = 1[x[...] ∈ set(pts)].
@@ -61,16 +110,10 @@ def match_points(x: jax.Array, pts: jax.Array) -> jax.Array:
     x: [k, mloc] int points or [k, mloc, F] feature rows;
     pts: [P] or [P, F] (need not be deduplicated).
     """
-    if x.ndim == 3:
-        flat = x.reshape(-1, x.shape[-1])
-        hit = jnp.any(jnp.all(flat[:, None, :] == pts[None], axis=-1),
-                      axis=-1)
-        return hit.reshape(x.shape[:2])
-    # int track: O((m+P)·log P) via sorted membership, not O(m·P)
-    ps = jnp.sort(pts)
-    xf = x.reshape(-1)
-    pos = jnp.clip(jnp.searchsorted(ps, xf), 0, pts.shape[0] - 1)
-    return (ps[pos] == xf).reshape(x.shape[:2])
+    ps = pts[_sort_order(pts)]
+    q = x.reshape((-1,) + x.shape[2:])
+    lo = jnp.minimum(_search(ps, q, "left"), ps.shape[0] - 1)
+    return _equal(ps[lo], q).reshape(x.shape[:2])
 
 
 def distinct_count(pts: jax.Array) -> jax.Array:
@@ -148,30 +191,39 @@ def dispute_table(x: np.ndarray, y: np.ndarray, alive0: np.ndarray,
     return pts[keep], pos[keep], neg[keep]
 
 
+def point_index(points: np.ndarray, pts: np.ndarray) -> np.ndarray:
+    """Host-side: for each of ``points`` ([M] or [M, F]) the index of an
+    entry of ``pts`` equal to it, −1 where there is none.
+    ``np.unique`` groups by ``==`` (a NaN equals nothing), in
+    O((M+P)·log(M+P)) where an all-pairs compare is O(M·P)."""
+    _, inv = np.unique(np.concatenate([pts, points]), axis=0,
+                       return_inverse=True, equal_nan=False)
+    inv = inv.reshape(-1)
+    of_group = np.full(inv.max() + 1, -1, np.int64)
+    of_group[inv[:len(pts)]] = np.arange(len(pts))
+    return of_group[inv[len(pts):]]
+
+
+def _point_index(x: np.ndarray, pts: np.ndarray) -> np.ndarray:
+    """:func:`point_index` of the points of shards x [k, mloc(, F)]."""
+    return point_index(x.reshape((-1,) + pts.shape[1:]),
+                       pts).reshape(x.shape[:2])
+
+
 def _kill_points(x: np.ndarray, alive: np.ndarray, pts: np.ndarray):
     """Remove every copy of every disputed point, on every player."""
-    if x.ndim == 3:                       # feature rows
-        flat = x.reshape(-1, x.shape[-1])
-        dead = (flat[:, None, :] == pts[None]).all(-1).any(-1)
-        dead = dead.reshape(x.shape[:2])
-    else:
-        dead = np.isin(x, pts)
-    return alive & ~dead
+    return alive & (_point_index(x, pts) < 0)
 
 
 def _point_counts(x: np.ndarray, y: np.ndarray, alive: np.ndarray,
                   pts: np.ndarray):
-    """Label counts of each disputed point over all (alive) copies in S."""
-    if x.ndim == 3:
-        flat = x.reshape(-1, x.shape[-1])
-        eq = (flat[:, None, :] == pts[None]).all(-1)        # [m, P]
-    else:
-        eq = x.reshape(-1)[:, None] == pts[None]            # [m, P]
+    """Label counts of each disputed point (``pts`` distinct) over all
+    (alive) copies in S."""
+    idx = _point_index(x, pts).reshape(-1)
     yf = y.reshape(-1)
-    af = alive.reshape(-1)
-    pos = ((yf > 0) & af)[:, None] & eq
-    neg = ((yf < 0) & af)[:, None] & eq
-    return pos.sum(0).astype(np.int64), neg.sum(0).astype(np.int64)
+    live = (idx >= 0) & alive.reshape(-1)
+    return (np.bincount(idx[live & (yf > 0)], minlength=len(pts)),
+            np.bincount(idx[live & (yf < 0)], minlength=len(pts)))
 
 
 def _emit_attempt(sp, att_led: Ledger, res, q_control: int,
@@ -299,15 +351,23 @@ class ResilientClassifier:
         gx = self.g(x).astype(jnp.int32)
         if self.dispute_x.shape[0] == 0:
             return gx.astype(jnp.int8)
-        if self.dispute_x.ndim == 2:                  # feature rows
-            eq = jnp.all(x[..., None, :] == self.dispute_x, axis=-1)
-        else:
-            eq = (x[..., None] == self.dispute_x)     # [..., P]
-        pos = jnp.sum(jnp.where(eq, self.dispute_pos, 0), axis=-1)
-        neg = jnp.sum(jnp.where(eq, self.dispute_neg, 0), axis=-1)
-        in_d = jnp.any(eq, axis=-1)
-        vote = jnp.where(pos >= neg, 1, -1)
-        out = jnp.where(in_d, vote, gx)
+        # counts summed over every dispute entry equal to x: the entries
+        # [lo, hi) of the sorted table, via prefix sums
+        order = _sort_order(self.dispute_x)
+        ps = self.dispute_x[order]
+
+        def prefix(c):
+            return jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                                    jnp.cumsum(c[order].astype(jnp.int32))])
+
+        lead = x.shape[:x.ndim - (ps.ndim - 1)]
+        q = x.reshape((-1,) + ps.shape[1:])
+        lo = _search(ps, q, "left")
+        hi = _search(ps, q, "right")
+        in_d = (hi > lo) & _equal(ps[jnp.minimum(lo, ps.shape[0] - 1)], q)
+        cpos, cneg = prefix(self.dispute_pos), prefix(self.dispute_neg)
+        vote = jnp.where(cpos[hi] - cpos[lo] >= cneg[hi] - cneg[lo], 1, -1)
+        out = jnp.where(in_d.reshape(lead), vote.reshape(lead), gx)
         return out.astype(jnp.int8)
 
 

@@ -593,15 +593,10 @@ def contradicted_points(task: tasks.Task) -> np.ndarray:
     """Points carrying BOTH labels in S — the sub-multiset no classifier
     can be consistent with (each contributes ≥ min(n₊, n₋) to OPT)."""
     xf, yf = task.flat_x, task.flat_y
-    if xf.ndim == 2:                     # feature rows: O(m²) but tiny m
-        eq = (xf[:, None, :] == xf[None]).all(-1)
-        both = ((eq & (yf[None] > 0)).any(1)
-                & (eq & (yf[None] < 0)).any(1))
-        pts = xf[both]
-        return np.unique(pts, axis=0) if pts.size else pts
-    vals = np.unique(xf)
-    pos = np.isin(vals, xf[yf > 0])
-    neg = np.isin(vals, xf[yf < 0])
+    vals, inv = np.unique(xf, axis=0, return_inverse=True, equal_nan=False)
+    inv = inv.reshape(-1)
+    pos = np.bincount(inv[yf > 0], minlength=len(vals)) > 0
+    neg = np.bincount(inv[yf < 0], minlength=len(vals)) > 0
     return vals[pos & neg]
 
 
@@ -611,12 +606,9 @@ def quarantine_recall(dispute_x: np.ndarray, target_pts: np.ndarray,
     tgt = np.asarray(target_pts)
     if tgt.shape[0] == 0:
         return 1.0
-    dis = np.asarray(dispute_x)
-    if tgt.ndim == 2:
-        hit = (dis[:, None, :] == tgt[None]).all(-1).any(0) \
-            if dis.shape[0] else np.zeros(tgt.shape[0], bool)
-    else:
-        hit = np.isin(tgt, dis)
+    from repro.core import classify
+
+    hit = classify.point_index(tgt, np.asarray(dispute_x)) >= 0
     return float(hit.mean())
 
 

@@ -67,11 +67,11 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.ckpt import msgpack_ckpt
-from repro.core import approximation, batched, classify, ledger as L
+from repro.core import approximation, batched, ledger as L
 from repro.core import streaming, weak
 from repro.core import weights as W
 from repro.core.pinned import pinned_argmax
-from repro.core.boost_attempt import _center_erm, _gather_coreset, _shard_map
+from repro.core.boost_attempt import _center_erm, _gather_coreset
 from repro.core.types import BoostConfig
 from repro.obs import trace as obs_trace
 
@@ -105,13 +105,9 @@ class _RoundCarry(NamedTuple):
 
 
 def _slice_player_keys(keys_all: jax.Array, kloc: int) -> jax.Array:
-    """This device's kloc keys out of the k per-player keys — sliced on
-    the raw key data because dynamic_slice on typed keys is flaky on the
-    pinned 0.4.x toolchain."""
+    """This device's kloc keys out of the k per-player keys."""
     pid = jax.lax.axis_index(AXIS)
-    data = jax.random.key_data(keys_all)                  # [k, key_words]
-    loc = jax.lax.dynamic_slice_in_dim(data, pid * kloc, kloc, axis=0)
-    return jax.random.wrap_key_data(loc)
+    return jax.lax.dynamic_slice_in_dim(keys_all, pid * kloc, kloc, axis=0)
 
 
 def _local_player_mask(player_alive: jax.Array, kloc: int) -> jax.Array:
@@ -192,7 +188,8 @@ def _round_body(cfg: BoostConfig, cls, k: int, x, y, alive, x_orders,
             g = jax.lax.all_gather(a, AXIS)
             return g.reshape((k,) + g.shape[2:])
 
-        h, loss = cls.erm_players(cx, cy, mix_loc / cfg.coreset_size,
+        h, loss = cls.erm_players(cx, cy,
+                                  W.erm_weights(mix_loc, cfg.coreset_size),
                                   all_gather=_ag)
     elif no_center:
         # §2.2: the first ALIVE player acts as center; only its device
@@ -376,15 +373,6 @@ def _one_step_sharded(cfg: BoostConfig, cls, k: int, no_center: bool,
     success = (~stuck) & (out.t >= bound)
     ended = stuck | success
     k_alive = jnp.sum(pa.astype(jnp.int32))
-    # ---- full-point quarantine: the pooled stuck coreset is replicated
-    # (it is the all_gather output); dead players' rows are masked out
-    # and each device kills its local copies.
-    core_flat = out.core_x.reshape((-1,) + out.core_x.shape[2:])
-    valid_flat = jnp.repeat(pa, cfg.coreset_size)
-    masked_flat = classify.mask_invalid_points(core_flat, valid_flat)
-    dead_new = s["alive"] & classify.match_points(x, masked_flat) & stuck
-    p_count = jnp.where(
-        stuck, classify.distinct_count_masked(core_flat, valid_flat), 0)
     awire_core = awire_core + out.wire_core
     awire_ws = awire_ws + out.wire_ws
     awire_hist = awire_hist + out.wire_hist
@@ -392,8 +380,8 @@ def _one_step_sharded(cfg: BoostConfig, cls, k: int, no_center: bool,
     nxt = {
         "attempt": jnp.where(ended, a + 1, a),
         "done": s["done"] | success,
-        "alive": s["alive"] & ~dead_new,
-        "disputed": s["disputed"] | dead_new,
+        "alive": s["alive"],
+        "disputed": s["disputed"],
         "key_data": key_data,
         "h_params": jnp.where(success, out.h_params, s["h_params"]),
         "rounds": jnp.where(success, out.t, s["rounds"]),
@@ -404,8 +392,7 @@ def _one_step_sharded(cfg: BoostConfig, cls, k: int, no_center: bool,
                                  s["hist_rounds"].at[a].set(out.t),
                                  s["hist_rounds"]),
         "hist_alive": hist_alive,
-        "hist_p": jnp.where(ended, s["hist_p"].at[a].set(p_count),
-                            s["hist_p"]),
+        "hist_p": s["hist_p"],
         "hist_players": s["hist_players"].at[a].add(k_alive),
         "hist_players_h": s["hist_players_h"].at[a].add(
             jnp.where(stuck, 0, k_alive)),
@@ -433,11 +420,30 @@ def _one_step_sharded(cfg: BoostConfig, cls, k: int, no_center: bool,
             ended, s["hist_wire_votes"].at[a].set(awire_votes),
             s["hist_wire_votes"]),
         "wire_bytes": s["wire_bytes"] + out.wire_bytes,
-        "wire_q_points": s["wire_q_points"] + k_alive * p_count,
-        "wire_q_counts": s["wire_q_counts"] + k_alive * p_count,
+        "wire_q_points": s["wire_q_points"],
+        "wire_q_counts": s["wire_q_counts"],
     }
-    return jax.tree_util.tree_map(
+    nxt = jax.tree_util.tree_map(
         lambda new, old: jnp.where(active, new, old), nxt, s)
+    return nxt, batched.Stuck(stuck & active, out.core_x, pa)
+
+
+def _quarantine_lane(cfg: BoostConfig, x, s: dict,
+                     st: batched.Stuck) -> dict:
+    """Full-point quarantine on this device's shard: the pooled stuck
+    coreset is replicated (it is the all_gather output); dead players'
+    rows are masked out and each device kills its local copies; every
+    sender then reports its P counts."""
+    dead_new, p_count = batched.quarantine(cfg, x, s["alive"], st)
+    k_alive = jnp.sum(st.senders.astype(jnp.int32))
+    return {**s,
+            "alive": s["alive"] & ~dead_new,
+            "disputed": s["disputed"] | dead_new,
+            "hist_p": jnp.where(
+                st.stuck, s["hist_p"].at[s["attempt"] - 1].set(p_count),
+                s["hist_p"]),
+            "wire_q_points": s["wire_q_points"] + k_alive * p_count,
+            "wire_q_counts": s["wire_q_counts"] + k_alive * p_count}
 
 
 @functools.lru_cache(maxsize=None)
@@ -466,9 +472,16 @@ def _build_sharded_step(mesh: Mesh, cfg: BoostConfig, cls,
 
         def body(carry):
             st, i = carry
-            st2 = jax.vmap(functools.partial(
+            st2, stuck = jax.vmap(functools.partial(
                 _one_step_sharded, cfg, cls, k, no_center))(
                 x, y, x_orders, sched, st)
+            # the pooled loss is replicated, so every device takes the
+            # same branch
+            st2 = jax.lax.cond(
+                jnp.any(stuck.stuck),
+                lambda st2: jax.vmap(functools.partial(
+                    _quarantine_lane, cfg))(x, st2, stuck),
+                lambda st2: st2, st2)
             return st2, i + 1
 
         out, _ = jax.lax.while_loop(cond, body, (state, jnp.int32(0)))
@@ -482,8 +495,8 @@ def _build_sharded_step(mesh: Mesh, cfg: BoostConfig, cls,
                        jax.random.split(jax.random.key(0), 1), cfg,
                        cls=cls)}
     in_specs = (sharded, sharded, P(), state_specs, P())
-    return jax.jit(_shard_map(per_device, mesh=mesh, in_specs=in_specs,
-                              out_specs=state_specs))
+    return jax.jit(jax.shard_map(per_device, mesh=mesh, in_specs=in_specs,
+                                 out_specs=state_specs, check_vma=False))
 
 
 def run_rounds_sharded(state: dict, x, y, cfg: BoostConfig, cls,

@@ -76,3 +76,23 @@ def mixture_weights(log_wsums: jax.Array) -> jax.Array:
     mx = jnp.where(jnp.isfinite(mx), mx, 0.0)
     w = jnp.exp2(shifted - mx)
     return w / jnp.maximum(jnp.sum(w), 1e-30)
+
+
+# Grid of the center ERM's per-example weights.  They total Σ mix = 1
+# (plus at most k·c·2^-24 of rounding), so every weight sum the ERM
+# forms — histogram cells, prefix sums, leaf sums — is a multiple of
+# 2^-23 below 2, and every error term (half such a sum or difference)
+# a multiple of 2^-24 below 1: all EXACT in f32.  Exact sums do not
+# depend on reduction order, which XLA picks per program: unsnapped,
+# the single-task host program and the vmapped batched program summed
+# one tree histogram in different orders, a zero-error tie between two
+# splits broke on the rounding residue, and the runs diverged.
+# Snapping moves a weight by ≤ 2^-24 (a total variation ≤ k·c·2^-24,
+# 5e-4 at k·c = 8192 — far inside the ε = 1/100 the coreset concedes).
+ERM_WEIGHT_GRID = 2.0 ** -23
+
+
+def erm_weights(mix: jax.Array, c: int) -> jax.Array:
+    """Per-example ERM weight of each player's c coreset rows: its
+    mixture weight / c, snapped to :data:`ERM_WEIGHT_GRID`."""
+    return jnp.round(mix / c / ERM_WEIGHT_GRID) * ERM_WEIGHT_GRID

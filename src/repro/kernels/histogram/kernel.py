@@ -10,26 +10,38 @@ with ``bin(x) = clip(floor(x·Q), 0, Q−1)`` over the fixed [0, 1) grid
 (the convention defined in ref.py) — the LightGBM-style histogram a
 greedy tree grower reduces to best (feature, bin) splits per node.
 
-Like the stump kernel, the one-hot bin-membership tile never hits HBM:
-each grid step materialises a (BC × BF × BQ) compare tile in
-VMEM/VREGs and contracts it immediately against the weight chunk (an
-MXU-shaped reduction, not a scatter — scatters are row-serial on both
-TPU and XLA:CPU).
+The one-hot bin-membership tile never hits HBM: each grid step builds a
+[BF·Qp, BC] compare tile in VMEM and contracts it at once on the MXU as
+ONE 2-D matmul, ``[W; WY] [2N, BC] · onehotᵀ`` → [2N, BF·Qp] (the
+A·Bᵀ form Mosaic lowers natively; a scatter would be row-serial).
 
-Grid: (N, F/BF, Q/BQ, c/BC), c innermost, both outputs accumulated
-across the c steps (revisited blocks — the standard Pallas reduction
-pattern).  VMEM per step ≈ BC·BF·4 + 2·BC·4 + BC·BF·BQ·4 +
-2·BF·BQ·4 ≈ 0.27 MiB at (128, 8, 64).
+Layout (what the TPU compiler requires of every block — the last two
+block dims divisible by (8, 128) or equal to the array's):
 
-Batched form (:func:`hist_batched_pallas`): the (task, node) pair is
-folded into the single OUTERMOST grid axis g = b·N + n — one launch
-serves one tree level of the center ERM of all B tasks (X is indexed
-by g // N, the weights by (g // N, g % N)).
+* X is transposed to [B, F, c] so the point axis is the lane axis; its
+  block is (BF, BC) with BC a multiple of 128 and BF the whole padded F
+  (or, for very wide F, a multiple of 8 — see :func:`feature_block`).
+* The bin axis is padded to Qp (a multiple of 8) so the compare tile
+  [BF, Qp, BC] collapses to [BF·Qp, BC] without a relayout; F is padded
+  so that BF·Qp is a multiple of 128 (a lane-aligned output tile).
+* W and WY are stacked into one [B, 2N, c] operand, block (2N, BC).
+* Leading (task, feature-block) block dims are squeezed (``None``).
+
+Grid: (B, F/BF, c/BC), c innermost, the [2N, BF·Qp] output block
+accumulated across the c steps (revisited blocks — the standard Pallas
+reduction pattern).  The dot runs at HIGHEST precision: the f32
+weights pass through exactly (the protocol snaps them to a dyadic grid,
+so every histogram cell is an exact f32 sum — see
+core/boost_attempt._center_erm).  VMEM per step, double-buffered
+inputs and output included, is :func:`vmem_bytes`: ≈ 3.7 MiB at
+N = 4, F = 28, Q = 64 (BC = 256), under a quarter of the 16 MiB of
+scoped VMEM a v5e kernel may use by default.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -37,99 +49,90 @@ from jax.experimental import pallas as pl
 
 from repro.kernels.histogram.ref import bin_index
 
-BC, BF, BQ = 128, 8, 64
+BC = 256                       # points per grid step (lane axis)
+ONEHOT_BUDGET = 2 * 2 ** 20    # bytes of one [BF·Qp, BC] f32 compare tile
 
 
-def _hist_kernel(bins, bq, x_ref, w_ref, wy_ref, hw_ref, hwy_ref):
-    qi, ci = pl.program_id(2), pl.program_id(3)
+def padded_bins(bins: int) -> int:
+    """Qp: the bin axis rounded up to a sublane multiple (8)."""
+    return -(-bins // 8) * 8
 
-    @pl.when(ci == 0)
+
+def feature_block(F: int, bins: int) -> tuple[int, int]:
+    """(BF, F_pad): features per grid step and the padded feature count.
+
+    BF·Qp is a multiple of 128 (lane-aligned output tile).  One block
+    holds every feature while its compare tile fits ONEHOT_BUDGET;
+    wider F is split into blocks that are also sublane multiples (8)."""
+    qp = padded_bins(bins)
+    unit = 128 // math.gcd(qp, 128)
+    if -(-F // unit) * unit * qp * BC * 4 <= ONEHOT_BUDGET:
+        bf = -(-F // unit) * unit
+        return bf, bf
+    step = unit * 8 // math.gcd(unit, 8)
+    bf = max(step, ONEHOT_BUDGET // (qp * BC * 4) // step * step)
+    return bf, -(-F // bf) * bf
+
+
+def vmem_bytes(n_nodes: int, F: int, bins: int) -> int:
+    """VMEM one grid step holds: double-buffered X tile, weight tile and
+    output block, plus the int32 iota and the f32 one-hot tile."""
+    bf, _ = feature_block(F, bins)
+    tile = bf * padded_bins(bins) * BC * 4
+    return (2 * bf * BC * 4 + 2 * 2 * n_nodes * BC * 4 + 2 * tile
+            + 2 * 2 * n_nodes * bf * padded_bins(bins) * 4)
+
+
+def _hist_kernel(bins, qp, xt_ref, lhs_ref, out_ref):
+    @pl.when(pl.program_id(2) == 0)
     def _init():
-        hw_ref[...] = jnp.zeros_like(hw_ref)
-        hwy_ref[...] = jnp.zeros_like(hwy_ref)
+        out_ref[...] = jnp.zeros_like(out_ref)
 
-    b = bin_index(x_ref[...], bins)               # [BC, BF]
-    qs = qi * bq + jnp.arange(bq, dtype=jnp.int32)
-    onehot = (b[:, :, None] == qs[None, None, :]).astype(jnp.float32)
-    hw_ref[0] += jnp.einsum("c,cfq->fq", w_ref[0], onehot)
-    hwy_ref[0] += jnp.einsum("c,cfq->fq", wy_ref[0], onehot)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("bins", "interpret", "blocks"))
-def hist_pallas(x, w, wy, *, bins: int, interpret: bool = False,
-                blocks=(BC, BF, BQ)):
-    """x [c, F] f32; w, wy [N, c] f32 → (hist_w, hist_wy) [N, F, Q] f32
-    with Q padded to the block grid.  c % BC == F % BF == Q % BQ == 0
-    (caller pads); ``bins`` is the true Q the bin map clips to."""
-    bc, bf, bq = blocks
-    c, F = x.shape
-    N = w.shape[0]
-    Q = ((bins + bq - 1) // bq) * bq
-    assert c % bc == 0 and F % bf == 0
-    out = jax.ShapeDtypeStruct((N, F, Q), jnp.float32)
-    return pl.pallas_call(
-        functools.partial(_hist_kernel, bins, bq),
-        grid=(N, F // bf, Q // bq, c // bc),
-        in_specs=[
-            pl.BlockSpec((bc, bf), lambda n, f, q, ci: (ci, f)),
-            pl.BlockSpec((1, bc), lambda n, f, q, ci: (n, ci)),
-            pl.BlockSpec((1, bc), lambda n, f, q, ci: (n, ci)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bf, bq), lambda n, f, q, ci: (n, f, q)),
-            pl.BlockSpec((1, bf, bq), lambda n, f, q, ci: (n, f, q)),
-        ],
-        out_shape=[out, out],
-        interpret=interpret,
-    )(x, w, wy)
+    b = bin_index(xt_ref[...], bins)                       # [BF, BC]
+    bf, bc = b.shape
+    q = jax.lax.broadcasted_iota(jnp.int32, (bf, qp, bc), 1)
+    onehot = (b[:, None, :] == q).astype(jnp.float32).reshape(bf * qp, bc)
+    out_ref[...] += jax.lax.dot_general(
+        lhs_ref[...], onehot, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)                # [2N, BF·Qp]
 
 
-def _hist_kernel_batched(bins, bq, x_ref, w_ref, wy_ref, hw_ref,
-                         hwy_ref):
-    qi, ci = pl.program_id(2), pl.program_id(3)
-
-    @pl.when(ci == 0)
-    def _init():
-        hw_ref[...] = jnp.zeros_like(hw_ref)
-        hwy_ref[...] = jnp.zeros_like(hwy_ref)
-
-    b = bin_index(x_ref[0], bins)                 # [BC, BF]
-    qs = qi * bq + jnp.arange(bq, dtype=jnp.int32)
-    onehot = (b[:, :, None] == qs[None, None, :]).astype(jnp.float32)
-    hw_ref[0, 0] += jnp.einsum("c,cfq->fq", w_ref[0, 0], onehot)
-    hwy_ref[0, 0] += jnp.einsum("c,cfq->fq", wy_ref[0, 0], onehot)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("bins", "interpret", "blocks"))
-def hist_batched_pallas(x, w, wy, *, bins: int, interpret: bool = False,
-                        blocks=(BC, BF, BQ)):
-    """x [B, c, F]; w, wy [B, N, c] → (hist_w, hist_wy) [B, N, F, Q].
-    One launch for one tree level of all B tasks: the outermost grid
-    axis is g = b·N + n (N static, so the index maps divide it out)."""
-    bc, bf, bq = blocks
+@functools.partial(jax.jit, static_argnames=("bins", "interpret"))
+def hist_batched_pallas(x, w, wy, *, bins: int, interpret: bool = False):
+    """x [B, c, F] f32; w, wy [B, N, c] f32 → (hist_w, hist_wy)
+    [B, N, F, bins] f32.  One launch for one tree level of all B tasks;
+    any c, F, bins (padding rows carry zero weight, padding features
+    and bins are sliced off)."""
     B, c, F = x.shape
     N = w.shape[1]
-    Q = ((bins + bq - 1) // bq) * bq
-    assert c % bc == 0 and F % bf == 0
-    out = jax.ShapeDtypeStruct((B, N, F, Q), jnp.float32)
-    return pl.pallas_call(
-        functools.partial(_hist_kernel_batched, bins, bq),
-        grid=(B * N, F // bf, Q // bq, c // bc),
+    qp = padded_bins(bins)
+    bf, fp = feature_block(F, bins)
+    cp = -(-c // BC) * BC
+    xt = jnp.pad(jnp.swapaxes(x, 1, 2), ((0, 0), (0, fp - F), (0, cp - c)))
+    lhs = jnp.pad(jnp.concatenate([w, wy], axis=1),
+                  ((0, 0), (0, 0), (0, cp - c)))
+    out = pl.pallas_call(
+        functools.partial(_hist_kernel, bins, qp),
+        grid=(B, fp // bf, cp // BC),
         in_specs=[
-            pl.BlockSpec((1, bc, bf), lambda g, f, q, ci: (g // N, ci, f)),
-            pl.BlockSpec((1, 1, bc),
-                         lambda g, f, q, ci: (g // N, g % N, ci)),
-            pl.BlockSpec((1, 1, bc),
-                         lambda g, f, q, ci: (g // N, g % N, ci)),
+            pl.BlockSpec((None, bf, BC), lambda b, f, ci: (b, f, ci)),
+            pl.BlockSpec((None, 2 * N, BC), lambda b, f, ci: (b, 0, ci)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1, bf, bq),
-                         lambda g, f, q, ci: (g // N, g % N, f, q)),
-            pl.BlockSpec((1, 1, bf, bq),
-                         lambda g, f, q, ci: (g // N, g % N, f, q)),
-        ],
-        out_shape=[out, out],
+        out_specs=pl.BlockSpec((None, None, 2 * N, bf * qp),
+                               lambda b, f, ci: (b, f, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, fp // bf, 2 * N, bf * qp),
+                                       jnp.float32),
         interpret=interpret,
-    )(x, w, wy)
+    )(xt, lhs)
+    out = out.reshape(B, fp // bf, 2 * N, bf, qp).transpose(0, 2, 1, 3, 4)
+    out = out.reshape(B, 2 * N, fp, qp)[:, :, :F, :bins]
+    return out[:, :N], out[:, N:]
+
+
+def hist_pallas(x, w, wy, *, bins: int, interpret: bool = False):
+    """x [c, F]; w, wy [N, c] → (hist_w, hist_wy) [N, F, bins]: the
+    single-task form, one task of :func:`hist_batched_pallas`."""
+    hw, hwy = hist_batched_pallas(x[None], w[None], wy[None], bins=bins,
+                                  interpret=interpret)
+    return hw[0], hwy[0]

@@ -1,9 +1,9 @@
-"""Public wrapper: pad to block multiples, dispatch, reduce to splits.
+"""Public wrapper: dispatch (ref or Pallas), reduce to splits.
 
 Both entry points accept an optional leading batch (task) axis:
-``x [c, F]`` uses the 4-D grid; ``x [B, c, F]`` lowers to the batched
-kernel whose outermost grid axis folds (task, node) — one launch for
-one tree level of the center ERM of all B tasks.
+``x [c, F]`` is one task of the kernel's grid; ``x [B, c, F]`` lowers
+to one launch whose outermost grid axis is the task — one tree level of
+the center ERM of all B tasks (kernel.py pads to its blocks).
 
 Routing policy (mirrors how the stump kernel is deployed): the Pallas
 program is the TPU fast path; on CPU the pure-jnp ref IS the production
@@ -25,20 +25,15 @@ from repro.kernels.histogram.ref import (  # noqa: F401  (re-export oracle)
     node_histograms_chunked_ref, node_histograms_ref, split_err_surface)
 
 
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
 def _pallas_histograms(x, w, wy, bins: int, interpret: bool):
-    batched = x.ndim == 3
-    c, F = x.shape[-2], x.shape[-1]
-    pc, pf = (-c) % K.BC, (-F) % K.BF
-    lead = ((0, 0),) if batched else ()
-    xp = jnp.pad(x, lead + ((0, pc), (0, pf)))      # pad rows: zero weight
-    wp = jnp.pad(w, lead + ((0, 0), (0, pc)))       # ⇒ no-op in every bin
-    wyp = jnp.pad(wy, lead + ((0, 0), (0, pc)))
-    if batched:
-        hw, hwy = K.hist_batched_pallas(xp, wp, wyp, bins=bins,
-                                        interpret=interpret)
-        return hw[:, :, :F, :bins], hwy[:, :, :F, :bins]
-    hw, hwy = K.hist_pallas(xp, wp, wyp, bins=bins, interpret=interpret)
-    return hw[:, :F, :bins], hwy[:, :F, :bins]
+    if x.ndim == 3:
+        return K.hist_batched_pallas(x, w, wy, bins=bins,
+                                     interpret=interpret)
+    return K.hist_pallas(x, w, wy, bins=bins, interpret=interpret)
 
 
 def _chunked_histograms(x, w, wy, bins: int, interpret: bool | None,
@@ -94,7 +89,7 @@ def node_histograms(x, w, wy, bins: int, interpret: bool | None = None,
     if chunk_size is not None and chunk_size < x.shape[-2]:
         return _chunked_histograms(x, w, wy, bins, interpret, chunk_size)
     if interpret is None:
-        if jax.default_backend() != "tpu":
+        if not _on_tpu():
             return node_histograms_ref(x, w, wy, bins)
         interpret = False
     return _pallas_histograms(x, w, wy, bins, interpret)

@@ -14,31 +14,21 @@ must set XLA_FLAGS before anything else).
 from __future__ import annotations
 
 import jax
-
-
-def make_mesh_compat(shape, axes):
-    """jax.make_mesh across jax versions: ``axis_types`` (and
-    ``jax.sharding.AxisType`` itself) only exist on newer jax; the
-    pinned 0.4.x toolchain takes the two-argument form."""
-    try:
-        from jax.sharding import AxisType
-    except ImportError:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes,
-                         axis_types=(AxisType.Auto,) * len(axes))
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model: int = 1):
     """Whatever this host actually has — for smoke tests / CPU runs."""
     n = len(jax.devices())
     data = n // model
-    return make_mesh_compat((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 # v5e hardware constants for the roofline (per chip)

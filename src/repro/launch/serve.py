@@ -53,6 +53,7 @@ import numpy as np
 
 from repro.configs import base
 from repro.core.pinned import pinned_argmax
+from repro.launch import compile_cache
 from repro.models import build, frontend
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
@@ -103,18 +104,10 @@ def run(args) -> dict:
     return result
 
 
-def run_classify(args) -> dict:
-    """Serve a batch of B boosting tasks in one jitted dispatch.
-
-    ``--scenario dropout/flaky/rejoin`` picks an *infrastructure*
-    adversary (core/scenarios.InfraSpec): the tasks carry the usual
-    ``--noise`` uniform flips, and a player-alive schedule silences
-    ``--infra-player`` per the adversary — the engines proceed with
-    k′ < k players and the report pins E_S(f) ≤ OPT over the surviving
-    shards plus the masked ledger (sharded engine validates it against
-    the measured collective payloads).
-    """
-    from repro.core import batched, scenarios, sharded_batched, tasks, weak
+def classify_config(args):
+    """(hypothesis class, BoostConfig) that ``--workload classify``
+    runs for these arguments."""
+    from repro.core import weak
     from repro.core.types import BoostConfig
 
     cls = weak.make_class(args.cls, n=args.domain,
@@ -127,6 +120,23 @@ def run_classify(args) -> dict:
         k=args.k, coreset_size=args.coreset, domain_size=args.domain,
         opt_budget=args.opt_budget,
         deterministic_coreset=not weak.needs_features(cls))
+    return cls, cfg
+
+
+def run_classify(args) -> dict:
+    """Serve a batch of B boosting tasks in one jitted dispatch.
+
+    ``--scenario dropout/flaky/rejoin`` picks an *infrastructure*
+    adversary (core/scenarios.InfraSpec): the tasks carry the usual
+    ``--noise`` uniform flips, and a player-alive schedule silences
+    ``--infra-player`` per the adversary — the engines proceed with
+    k′ < k players and the report pins E_S(f) ≤ OPT over the surviving
+    shards plus the masked ledger (sharded engine validates it against
+    the measured collective payloads).
+    """
+    from repro.core import batched, scenarios, sharded_batched, tasks
+
+    cls, cfg = classify_config(args)
     B = args.batch
     infra = args.scenario if args.scenario in scenarios.INFRA else None
     noise_scenario = None if infra else args.scenario
@@ -390,6 +400,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main():
     args = build_parser().parse_args()
+    compile_cache.enable()
     rec = obs_trace.enable() if args.trace_out else None
     try:
         if args.workload == "serve-stream":

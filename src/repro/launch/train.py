@@ -28,6 +28,7 @@ from repro.ckpt import CheckpointManager
 from repro.configs import base
 from repro.core import resilient
 from repro.data import DataConfig, SyntheticCorpus
+from repro.launch import compile_cache
 from repro.models import build
 from repro.optim import adamw_init
 
@@ -119,6 +120,7 @@ def main():
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=100)
     args = ap.parse_args()
+    compile_cache.enable()
     run(args)
 
 

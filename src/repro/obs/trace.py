@@ -227,8 +227,7 @@ def annotate(name: str):
     otherwise."""
     if _ACTIVE is None:
         return _NULL_SPAN
-    ann = getattr(jax.profiler, "TraceAnnotation", None)
-    return ann(name) if ann is not None else _NULL_SPAN
+    return jax.profiler.TraceAnnotation(name)
 
 
 @contextlib.contextmanager

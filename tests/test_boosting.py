@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
 from repro.core import (approximation, boost_attempt, classify, ledger,
                         tasks, weak, weights)
@@ -109,8 +110,7 @@ def test_quantile_coreset_is_approximation():
 
 def test_sharded_equals_reference():
     """shard_map form on a 1-device mesh reproduces the k=1 reference."""
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     cls = weak.Thresholds(n=N)
     task = tasks.make_task(cls, m=1024, k=1, noise=0, seed=5)
     cfg = BoostConfig(k=1, coreset_size=400, domain_size=N)
@@ -150,8 +150,7 @@ def test_log_weight_math():
 def test_no_center_model_equivalent():
     """§2.2: the no-center protocol (player 0 acts as center) produces
     a consistent classifier identical in outcome to the center model."""
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     cls = weak.Thresholds(n=N)
     task = tasks.make_task(cls, m=1024, k=1, noise=0, seed=9)
     cfg = BoostConfig(k=1, coreset_size=400, domain_size=N)

@@ -277,6 +277,88 @@ def test_masked_point_helpers_int_and_float():
     assert not bool(classify.match_points(rows[None, 1:2], mrows)[0, 0])
 
 
+@pytest.mark.parametrize("F", [0, 1, 4])
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_point_lookups_equal_brute_force(seed, F):
+    """The sorted point lookups — device match, host quarantine and
+    label counts, the classifier's dispute vote — equal the all-pairs
+    ``==`` compare on int points (F = 0) and on float rows with shared
+    prefixes, duplicates, −0.0 against +0.0, masked (NaN) rows and rows
+    holding one NaN."""
+    import jax.numpy as jnp
+
+    from repro.core import classify
+
+    rng = np.random.default_rng(seed)
+    k, mloc, P = 3, 40, 33
+    if F:
+        x = rng.integers(0, 3, (k, mloc, F)).astype(np.float32) / 2
+        x[0, :5, 0] = -0.0
+        x[1, 0, F - 1] = np.nan
+    else:
+        x = rng.integers(0, 60, (k, mloc)).astype(np.int32)
+    flat = x.reshape((k * mloc,) + x.shape[2:])
+    pts = flat[rng.choice(k * mloc, P)]
+    pts[:3] = (rng.integers(0, 3, (3, F)) / 2 if F
+               else rng.integers(60, 63, 3))            # absent points
+    if F:
+        pts[3, 0] = np.nan
+    valid = rng.random(P) > 0.2
+    masked = np.asarray(classify.mask_invalid_points(jnp.asarray(pts),
+                                                     jnp.asarray(valid)))
+
+    def brute(a, b):                                   # [len a, len b]
+        eq = a.reshape(len(a), 1, -1) == b.reshape(1, len(b), -1)
+        return eq.all(-1)
+
+    eq = brute(flat, masked)
+    assert eq.any() and not eq.any(-1).all()
+    got = classify.match_points(jnp.asarray(x), jnp.asarray(masked))
+    np.testing.assert_array_equal(np.asarray(got),
+                                  eq.any(-1).reshape(k, mloc))
+
+    uniq = np.unique(pts, axis=0)
+    alive = rng.random((k, mloc)) > 0.3
+    y = rng.choice(np.array([-1, 1], np.int8), (k, mloc))
+    eq = brute(flat, uniq)
+    np.testing.assert_array_equal(
+        classify._kill_points(x, alive, uniq),
+        alive & ~eq.any(-1).reshape(k, mloc))
+    pos, neg = classify._point_counts(x, y, alive, uniq)
+    live = alive.reshape(-1, 1) & eq
+    np.testing.assert_array_equal(pos, (live & (y.reshape(-1, 1) > 0)).sum(0))
+    np.testing.assert_array_equal(neg, (live & (y.reshape(-1, 1) < 0)).sum(0))
+    np.testing.assert_array_equal(
+        scenarios.quarantine_recall(uniq, flat),
+        brute(flat, uniq).any(-1).mean())
+
+    # points carrying both labels in S
+    eq = brute(flat, flat)
+    yf = y.reshape(-1)
+    both = (eq & (yf > 0)).any(-1) & (eq & (yf < 0)).any(-1)
+    task = tasks.Task(x=x, y=y, target_params=None, noise_count=0, cls=None)
+    got = scenarios.contradicted_points(task)
+    np.testing.assert_array_equal(got, np.unique(flat[both], axis=0))
+    assert 0 < len(got)
+
+    # the vote sums counts over repeated dispute entries
+    dx = np.concatenate([uniq, uniq[:4]])
+    dpos = rng.integers(0, 4, len(dx))
+    dneg = rng.integers(0, 4, len(dx))
+    cls = weak.make_class("tree", num_features=max(F, 1)) if F else \
+        weak.make_class("thresholds", n=64)
+    f = classify.ResilientClassifier(
+        cls=cls, hypotheses=jnp.zeros((1, weak.param_dim(cls)), jnp.float32),
+        rounds=0, dispute_x=jnp.asarray(dx), dispute_pos=jnp.asarray(dpos),
+        dispute_neg=jnp.asarray(dneg))
+    eq = brute(flat, dx)
+    want = np.where(eq.any(-1),
+                    np.where(eq @ dpos >= eq @ dneg, 1, -1),
+                    np.asarray(f.g(jnp.asarray(flat))))
+    np.testing.assert_array_equal(np.asarray(f(jnp.asarray(x))),
+                                  want.reshape(k, mloc))
+
+
 def test_canon_player_sched_rejects_dead_rounds():
     with pytest.raises(ValueError):
         batched.canon_player_sched(np.zeros((2, 4), bool), B=1, k=4)

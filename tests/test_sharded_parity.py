@@ -20,7 +20,7 @@ import jax.numpy as jnp
 
 from repro.core import boost_attempt, tasks, weak
 from repro.core.types import BoostConfig
-from repro.launch.mesh import make_mesh_compat
+from jax.sharding import AxisType
 
 assert jax.device_count() == 2, jax.devices()
 
@@ -37,7 +37,7 @@ for noise, seed in ((0, 5), (3, 8)):
     ref = boost_attempt.run_boost_attempt(
         xk, yk, jnp.ones_like(xk, bool), jax.random.key(0), cfg, cls)
 
-    mesh = make_mesh_compat((2,), ("data",))
+    mesh = jax.make_mesh((2,), ("data",), axis_types=(AxisType.Auto,))
     x = xk.reshape(-1)
     y = yk.reshape(-1)
     args = (x, y, jnp.ones_like(x, bool), jnp.zeros_like(x),

@@ -466,7 +466,7 @@ class HostPurity(SourceRule):
 # Last dotted component of callables that put a function argument inside
 # a trace: passing `f` by name to any of these makes `f`'s body traced.
 _TRANSFORMS = {
-    "jit", "vmap", "pmap", "shard_map", "_shard_map",
+    "jit", "vmap", "pmap", "shard_map",
     "while_loop", "scan", "fori_loop", "cond", "switch",
     "checkpoint", "remat",
 }
