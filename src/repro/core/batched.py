@@ -60,6 +60,7 @@ import numpy as np
 from repro.ckpt import msgpack_ckpt
 from repro.core import boost_attempt, classify, ledger as L, streaming, weak
 from repro.core import weights as W
+from repro.core.boost_attempt import step_scope
 from repro.core.types import BoostConfig, ClassifyResult, Ledger
 from repro.obs import trace as obs_trace
 
@@ -241,26 +242,30 @@ def _one_step(cfg: BoostConfig, cls, x, y, x_orders, sched,
     parity tests (tests/test_sharded_batched.py) fail on divergence.
     """
     a_max = cfg.opt_budget + 1
-    active = (~s.done) & (s.attempt < a_max)
     k = x.shape[0]
-    pa = sched[jnp.minimum(s.step, sched.shape[0] - 1)]          # [k]
-    # ---- attempt start (no-op when one is already in flight) ----------
-    start = ~s.in_attempt
-    tkey = jax.random.wrap_key_data(s.key_data)
-    nk, sub = jax.random.split(tkey)
-    key_data = jnp.where(start, jax.random.key_data(nk), s.key_data)
-    akey_data = jnp.where(start, jax.random.key_data(sub), s.akey_data)
-    m_alive = jnp.sum((s.alive & pa[:, None]).astype(jnp.int32))
-    a = s.attempt
-    bound = jnp.where(start, num_rounds_dynamic(cfg, m_alive), s.bound)
-    hits = jnp.where(start, W.init_hits(x.shape[:2]), s.hits)
-    cur_h = jnp.where(start, jnp.zeros_like(s.cur_h), s.cur_h)
-    t = jnp.where(start, 0, s.t)
-    hist_alive = jnp.where(start, s.hist_alive.at[a].set(m_alive),
-                           s.hist_alive)
+    with step_scope("lane_state"):
+        active = (~s.done) & (s.attempt < a_max)
+        pa = sched[jnp.minimum(s.step, sched.shape[0] - 1)]      # [k]
+        # ---- attempt start (no-op when one is already in flight) ------
+        start = ~s.in_attempt
+        tkey = jax.random.wrap_key_data(s.key_data)
+        nk, sub = jax.random.split(tkey)
+        key_data = jnp.where(start, jax.random.key_data(nk), s.key_data)
+        akey_data = jnp.where(start, jax.random.key_data(sub),
+                              s.akey_data)
+        m_alive = jnp.sum((s.alive & pa[:, None]).astype(jnp.int32))
+        a = s.attempt
+        bound = jnp.where(start, num_rounds_dynamic(cfg, m_alive),
+                          s.bound)
+        hits = jnp.where(start, W.init_hits(x.shape[:2]), s.hits)
+        cur_h = jnp.where(start, jnp.zeros_like(s.cur_h), s.cur_h)
+        t = jnp.where(start, 0, s.t)
+        hist_alive = jnp.where(start, s.hist_alive.at[a].set(m_alive),
+                               s.hist_alive)
     # ---- one BoostAttempt round (the reference round body) ------------
-    y_sorted = jnp.take_along_axis(y, x_orders, axis=1)
-    alive_sorted = jnp.take_along_axis(s.alive, x_orders, axis=1)
+    with step_scope("sort_order"):
+        y_sorted = jnp.take_along_axis(y, x_orders, axis=1)
+        alive_sorted = jnp.take_along_axis(s.alive, x_orders, axis=1)
     carry = boost_attempt._Carry(
         t=t, it=jnp.int32(0), stuck=jnp.asarray(False),
         hits=hits, key=jax.random.wrap_key_data(akey_data),
@@ -270,40 +275,41 @@ def _one_step(cfg: BoostConfig, cls, x, y, x_orders, sched,
     out = boost_attempt._round_body(
         cfg, cls, x, y, s.alive, x_orders, y_sorted, alive_sorted,
         carry, player_alive=pa)
-    stuck = out.stuck
-    success = (~stuck) & (out.t >= bound)
-    ended = stuck | success
-    k_alive = jnp.sum(pa.astype(jnp.int32))
-    nxt = StepState(
-        attempt=jnp.where(ended, a + 1, a),
-        done=s.done | success,
-        alive=s.alive,
-        disputed=s.disputed,
-        key_data=key_data,
-        h_params=jnp.where(success, out.h_params, s.h_params),
-        rounds=jnp.where(success, out.t, s.rounds),
-        min_loss=out.min_loss,
-        hist_stuck=jnp.where(ended, s.hist_stuck.at[a].set(stuck),
-                             s.hist_stuck),
-        hist_rounds=jnp.where(ended, s.hist_rounds.at[a].set(out.t),
-                              s.hist_rounds),
-        hist_alive=hist_alive,
-        hist_p=s.hist_p,
-        hist_players=s.hist_players.at[a].add(k_alive),
-        hist_players_h=s.hist_players_h.at[a].add(
-            jnp.where(stuck, 0, k_alive)),
-        hist_players_last=s.hist_players_last.at[a].set(k_alive),
-        in_attempt=~ended,
-        akey_data=jax.random.key_data(out.key),
-        t=out.t,
-        bound=bound,
-        hits=out.hits,
-        cur_h=out.h_params,
-        core_x=out.core_x, core_y=out.core_y,
-        step=s.step + 1)
-    # finished lanes freeze (vmap-of-while masking)
-    nxt = jax.tree_util.tree_map(
-        lambda new, old: jnp.where(active, new, old), nxt, s)
+    with step_scope("lane_state"):
+        stuck = out.stuck
+        success = (~stuck) & (out.t >= bound)
+        ended = stuck | success
+        k_alive = jnp.sum(pa.astype(jnp.int32))
+        nxt = StepState(
+            attempt=jnp.where(ended, a + 1, a),
+            done=s.done | success,
+            alive=s.alive,
+            disputed=s.disputed,
+            key_data=key_data,
+            h_params=jnp.where(success, out.h_params, s.h_params),
+            rounds=jnp.where(success, out.t, s.rounds),
+            min_loss=out.min_loss,
+            hist_stuck=jnp.where(ended, s.hist_stuck.at[a].set(stuck),
+                                 s.hist_stuck),
+            hist_rounds=jnp.where(ended, s.hist_rounds.at[a].set(out.t),
+                                  s.hist_rounds),
+            hist_alive=hist_alive,
+            hist_p=s.hist_p,
+            hist_players=s.hist_players.at[a].add(k_alive),
+            hist_players_h=s.hist_players_h.at[a].add(
+                jnp.where(stuck, 0, k_alive)),
+            hist_players_last=s.hist_players_last.at[a].set(k_alive),
+            in_attempt=~ended,
+            akey_data=jax.random.key_data(out.key),
+            t=out.t,
+            bound=bound,
+            hits=out.hits,
+            cur_h=out.h_params,
+            core_x=out.core_x, core_y=out.core_y,
+            step=s.step + 1)
+        # finished lanes freeze (vmap-of-while masking)
+        nxt = jax.tree_util.tree_map(
+            lambda new, old: jnp.where(active, new, old), nxt, s)
     return nxt, Stuck(stuck & active, out.core_x, pa)
 
 
@@ -349,26 +355,30 @@ def _run_steps(x, y, sched, state: StepState, n, cfg: BoostConfig,
     x1d = x if x.ndim == 3 else x[..., 0]
     # hoisted per slice; chunk-local runs under cfg.chunk_size (bitwise
     # identical to the monolithic argsort — streaming tier)
-    x_orders = jax.vmap(jax.vmap(lambda v: streaming.sort_order(
-        v, cfg.chunk_size, cfg.domain_size)))(x1d)
+    with step_scope("sort_order"):
+        x_orders = jax.vmap(jax.vmap(lambda v: streaming.sort_order(
+            v, cfg.chunk_size, cfg.domain_size)))(x1d)
 
     def active(s: StepState):
         return (~s.done) & (s.attempt < a_max)
 
     def cond(carry):
         s, i = carry
-        return jnp.any(active(s)) & (i < n)
+        with step_scope("lane_state"):
+            return jnp.any(active(s)) & (i < n)
 
     def body(carry):
         s, i = carry
         s2, st = jax.vmap(functools.partial(_one_step, cfg, cls))(
             x, y, x_orders, sched, s)
-        s2 = jax.lax.cond(
-            jnp.any(st.stuck),
-            lambda s2: jax.vmap(functools.partial(_quarantine_lane, cfg))(
-                x, s2, st),
-            lambda s2: s2, s2)
-        return s2, i + 1
+        with step_scope("quarantine"):
+            s2 = jax.lax.cond(
+                jnp.any(st.stuck),
+                lambda s2: jax.vmap(functools.partial(
+                    _quarantine_lane, cfg))(x, s2, st),
+                lambda s2: s2, s2)
+        with step_scope("lane_state"):
+            return s2, i + 1
 
     out, _ = jax.lax.while_loop(cond, body, (state, jnp.int32(0)))
     return out
@@ -411,15 +421,15 @@ def run_rounds(state: StepState, x, y, cfg: BoostConfig, cls,
     sched = canon_player_sched(player_sched, B, k)
     n_arr = _RUN_FOREVER if n is None else jnp.int32(n)
     with obs_trace.span("run_rounds", "engine", engine="batched", B=B,
-                        n=(-1 if n is None else int(n))), \
-            obs_trace.annotate("run_rounds"):
+                        n=(-1 if n is None else int(n))):
         return _run_rounds_jit(x, y, sched, state, n_arr, cfg, cls)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "cls", "t_buf"))
 def _classify_batched_jit(x, y, alive0, keys, sched, cfg, cls, t_buf):
-    state = init_state(x, y, keys, cfg, alive=alive0, t_buf=t_buf,
-                       cls=cls)
+    with step_scope("lane_state"):
+        state = init_state(x, y, keys, cfg, alive=alive0, t_buf=t_buf,
+                           cls=cls)
     return _run_steps(x, y, sched, state, _RUN_FOREVER, cfg, cls)
 
 
@@ -461,9 +471,9 @@ def lower_classify(x, y, alive, keys, cfg: BoostConfig, cls,
     sched = canon_player_sched(player_sched, x.shape[0], x.shape[1])
     with obs_trace.span("compile", "compile", engine="batched",
                         B=int(x.shape[0]), mloc=int(x.shape[2])):
-        return _classify_batched_jit.lower(
-            jnp.asarray(x), jnp.asarray(y), jnp.asarray(alive), keys,
-            sched, cfg, cls, t_buf).compile()
+        return obs_trace.compile_published(
+            _classify_batched_jit, jnp.asarray(x), jnp.asarray(y),
+            jnp.asarray(alive), keys, sched, cfg, cls, t_buf)
 
 
 @dataclasses.dataclass
@@ -598,16 +608,17 @@ def finalize(state: StepState, x, y, alive0, cfg: BoostConfig, cls,
     materialisation — no protocol math happens here, so finalizing a
     restored checkpoint equals finalizing the original state bit for
     bit (tests/test_preemption.py)."""
-    with obs_trace.span("finalize", "engine", engine="batched"):
+    with obs_trace.span("finalize.state", "engine", engine="batched"):
         out = jax.device_get(state)
+    with obs_trace.span("finalize.inputs", "engine", engine="batched"):
+        x, y, alive0 = np.asarray(x), np.asarray(y), np.asarray(alive0)
     return BatchedClassifyResult(
         hypotheses=out.h_params, rounds=out.rounds,
         ok=np.asarray(out.done), attempts=out.attempt,
         alive=out.alive, disputed=out.disputed, min_loss=out.min_loss,
         hist_stuck=out.hist_stuck, hist_rounds=out.hist_rounds,
         hist_alive=out.hist_alive, hist_p=out.hist_p,
-        x=np.asarray(x), y=np.asarray(y), alive0=np.asarray(alive0),
-        cfg=cfg, cls=cls,
+        x=x, y=y, alive0=alive0, cfg=cfg, cls=cls,
         m_true=None if m_true is None else np.asarray(m_true),
         hist_players=out.hist_players,
         hist_players_h=out.hist_players_h,

@@ -59,6 +59,19 @@ def _gather_coreset(x, y, idx):
     return cx, take(y, idx)
 
 
+def step_scope(step: str):
+    """The ``jax.named_scope`` of one protocol step of the round body.
+
+    ``step`` is one of ``repro.obs.trace.ROUND_STEPS``.  A named scope
+    is device-side metadata (no ops, no host work): the compiler keeps
+    it in each instruction's ``op_name``, where
+    ``repro.obs.trace.op_steps`` reads it back.  It is NOT an obs
+    emission, so RL006 permits it in traced code."""
+    if step not in obs_trace.ROUND_STEPS:
+        raise ValueError(f"unknown round step {step!r}")
+    return jax.named_scope(step)
+
+
 def _center_erm(cls, cx, cy, mix, c):
     """Pooled-coreset ERM under the mixture D_t (step 2(c)+(d)).
 
@@ -69,11 +82,9 @@ def _center_erm(cls, cx, cy, mix, c):
     sharded engine's real collectives produce (bit-parity per mode).
     """
     k = cy.shape[0]
-    pw = W.erm_weights(mix, c)
-    # jax.named_scope is device-side metadata (it adds no ops and no
-    # host work) — profiler traces group the ERM under this label; it
-    # is NOT an obs emission, so RL006 permits it in traced code
-    with jax.named_scope("center_erm"):
+    with step_scope("weight_sums"):
+        pw = W.erm_weights(mix, c)
+    with step_scope("center_erm"):
         if getattr(cls, "comm_mode", "coreset") != "coreset":
             return cls.erm_players(cx, cy, pw)
         w = jnp.broadcast_to(pw[:, None], (k, c)).reshape(-1)
@@ -85,41 +96,48 @@ def _center_erm(cls, cx, cy, mix, c):
 def _round_body(cfg: BoostConfig, cls, x, y, alive, x_orders,
                 y_sorted, alive_sorted, carry: _Carry, *,
                 player_alive=None) -> _Carry:
-    key, kc = jax.random.split(carry.key)
-    keys = jax.random.split(kc, x.shape[0])
     # --- players: step 2(a) coreset + step 2(b) weight sums -------------
-    idx = jax.vmap(
-        lambda kk, xx, yy, hh, aa, oo, yso, aso:
-        approximation.select_coreset(
-            kk, xx if xx.ndim == 1 else xx[:, 0], yy, hh, aa,
-            cfg.coreset_size, cfg.deterministic_coreset and x.ndim == 2,
-            order=oo, y_sorted=yso, alive_sorted=aso)
-    )(keys, x, y, carry.hits, alive, x_orders, y_sorted, alive_sorted)
-    cx, cy = _gather_coreset(x, y, idx)
-    log_wsums = jax.vmap(W.log_weight_sum)(carry.hits, alive)     # [k]
-    if player_alive is not None:
-        # a player absent this round sends nothing: its weight sum is
-        # excluded from the mixture (−inf ⇒ mixture weight 0, so its
-        # coreset entries carry zero weight in the center ERM — the
-        # candidate behaviours they add are sound: zero-weight points
-        # can only certify MORE hypotheses, never hide a good one)
-        log_wsums = jnp.where(player_alive, log_wsums, -jnp.inf)
-    mix = W.mixture_weights(log_wsums)
+    with step_scope("coreset_draw"):
+        key, kc = jax.random.split(carry.key)
+        keys = jax.random.split(kc, x.shape[0])
+        idx = jax.vmap(
+            lambda kk, xx, yy, hh, aa, oo, yso, aso:
+            approximation.select_coreset(
+                kk, xx if xx.ndim == 1 else xx[:, 0], yy, hh, aa,
+                cfg.coreset_size,
+                cfg.deterministic_coreset and x.ndim == 2,
+                order=oo, y_sorted=yso, alive_sorted=aso)
+        )(keys, x, y, carry.hits, alive, x_orders, y_sorted, alive_sorted)
+        cx, cy = _gather_coreset(x, y, idx)
+    with step_scope("weight_sums"):
+        log_wsums = jax.vmap(W.log_weight_sum)(carry.hits, alive)  # [k]
+        if player_alive is not None:
+            # a player absent this round sends nothing: its weight sum
+            # is excluded from the mixture (−inf ⇒ mixture weight 0, so
+            # its coreset entries carry zero weight in the center ERM —
+            # the candidate behaviours they add are sound: zero-weight
+            # points can only certify MORE hypotheses, never hide a
+            # good one)
+            log_wsums = jnp.where(player_alive, log_wsums, -jnp.inf)
+        mix = W.mixture_weights(log_wsums)
     # --- center: step 2(c)+(d) weighted ERM over the pooled coreset -----
     h, loss = _center_erm(cls, cx, cy, mix, cfg.coreset_size)
-    stuck_now = loss > cfg.weak_threshold
     # --- players: step 2(f) multiplicative-weights update ---------------
-    pred = cls.predict(h, x)
-    correct = (pred == y)
-    upd = W.update_hits(carry.hits, correct, alive)
-    if player_alive is not None:
-        # absent players never received h_t: their MW state freezes
-        upd = jnp.where(player_alive[:, None], upd, carry.hits)
-    new_hits = jnp.where(stuck_now, carry.hits, upd)
-    h_params = carry.h_params.at[carry.t].set(
-        jnp.where(stuck_now, carry.h_params[carry.t], h))
+    with step_scope("predict"):
+        pred = cls.predict(h, x)
+    with step_scope("mw_update"):
+        stuck_now = loss > cfg.weak_threshold
+        correct = (pred == y)
+        upd = W.update_hits(carry.hits, correct, alive)
+        if player_alive is not None:
+            # absent players never received h_t: their MW state freezes
+            upd = jnp.where(player_alive[:, None], upd, carry.hits)
+        new_hits = jnp.where(stuck_now, carry.hits, upd)
+        h_params = carry.h_params.at[carry.t].set(
+            jnp.where(stuck_now, carry.h_params[carry.t], h))
+        t = jnp.where(stuck_now, carry.t, carry.t + 1)
     return _Carry(
-        t=jnp.where(stuck_now, carry.t, carry.t + 1),
+        t=t,
         it=carry.it + 1,
         stuck=stuck_now,
         hits=new_hits,
@@ -163,12 +181,13 @@ def boost_attempt_arrays(x, y, alive, hits0, key, cfg: BoostConfig, cls,
     # round loop; §Perf P4: so are the y/alive gathers into sorted space.
     # With cfg.chunk_size the order is built from chunk-local sorted
     # runs (streaming tier) — bitwise identical, never sorts > a chunk.
-    if x_orders is None:
-        x1d = x if x.ndim == 2 else x[:, :, 0]
-        x_orders = jax.vmap(lambda v: streaming.sort_order(
-            v, cfg.chunk_size, cfg.domain_size))(x1d)
-    y_sorted = jnp.take_along_axis(y, x_orders, axis=1)
-    alive_sorted = jnp.take_along_axis(alive, x_orders, axis=1)
+    with step_scope("sort_order"):
+        if x_orders is None:
+            x1d = x if x.ndim == 2 else x[:, :, 0]
+            x_orders = jax.vmap(lambda v: streaming.sort_order(
+                v, cfg.chunk_size, cfg.domain_size))(x1d)
+        y_sorted = jnp.take_along_axis(y, x_orders, axis=1)
+        alive_sorted = jnp.take_along_axis(alive, x_orders, axis=1)
     return jax.lax.while_loop(
         cond, functools.partial(_round_body, cfg, cls, x, y, alive,
                                 x_orders, y_sorted, alive_sorted), carry)
@@ -187,8 +206,7 @@ def run_boost_attempt(x, y, alive, key, cfg: BoostConfig,
     num_rounds = cfg.num_rounds(max(m, 2))
     hits0 = W.init_hits(x.shape[:2])
     with obs_trace.span("boost_attempt", "attempt", m_alive=m,
-                        bound=num_rounds) as sp, \
-            obs_trace.annotate("boost_attempt"):
+                        bound=num_rounds) as sp:
         out = _boost_attempt_jit(x, y, alive, hits0, key, cfg, cls,
                                  num_rounds)
         out = jax.device_get(out)
@@ -234,36 +252,41 @@ def boost_attempt_sharded(mesh, cfg: BoostConfig, cls, num_rounds: int,
         # outside the round loop instead of inside every coreset build
         # (chunk-local runs under cfg.chunk_size, bitwise identical).
         x1d = xl[0] if xl.ndim == 2 else xl[0, :, 0]
-        x_order = (streaming.sort_order(x1d, cfg.chunk_size,
-                                        cfg.domain_size)
-                   if cfg.deterministic_coreset else None)
-        y_sorted = yl[0][x_order] if x_order is not None else None
-        alive_sorted = al[0][x_order] if x_order is not None else None
+        with step_scope("sort_order"):
+            x_order = (streaming.sort_order(x1d, cfg.chunk_size,
+                                            cfg.domain_size)
+                       if cfg.deterministic_coreset else None)
+            y_sorted = yl[0][x_order] if x_order is not None else None
+            alive_sorted = (al[0][x_order] if x_order is not None
+                            else None)
 
         def round_body(carry):
             t, it, stuck, hitsl, kkey, h_params, last_loss = carry
-            kkey, kc = jax.random.split(kkey)
-            # identical key on all players is fine: sampling uses the
-            # per-player fold below.
             pid = jax.lax.axis_index(axes)
-            kp = jax.random.fold_in(kc, pid)
-            idx = approximation.select_coreset(
-                kp, x1d, yl[0],
-                hitsl[0], al[0], cfg.coreset_size,
-                cfg.deterministic_coreset and xl.ndim == 2,
-                order=x_order, y_sorted=y_sorted,
-                alive_sorted=alive_sorted)
-            cx, cy = _gather_coreset(xl, yl, idx[None])
-            log_wsum = W.log_weight_sum(hitsl[0], al[0])
-            # --- the wire: gather tiny coresets + one scalar per player --
-            cx_all = jax.lax.all_gather(cx[0], axes, tiled=False)
-            cy_all = jax.lax.all_gather(cy[0], axes, tiled=False)
-            ws_all = jax.lax.all_gather(log_wsum, axes, tiled=False)
+            with step_scope("coreset_draw"):
+                kkey, kc = jax.random.split(kkey)
+                # identical key on all players is fine: sampling uses
+                # the per-player fold below.
+                kp = jax.random.fold_in(kc, pid)
+                idx = approximation.select_coreset(
+                    kp, x1d, yl[0],
+                    hitsl[0], al[0], cfg.coreset_size,
+                    cfg.deterministic_coreset and xl.ndim == 2,
+                    order=x_order, y_sorted=y_sorted,
+                    alive_sorted=alive_sorted)
+                cx, cy = _gather_coreset(xl, yl, idx[None])
+                # the wire: gather tiny coresets + one scalar per player
+                cx_all = jax.lax.all_gather(cx[0], axes, tiled=False)
+                cy_all = jax.lax.all_gather(cy[0], axes, tiled=False)
+            with step_scope("weight_sums"):
+                log_wsum = W.log_weight_sum(hitsl[0], al[0])
+                ws_all = jax.lax.all_gather(log_wsum, axes, tiled=False)
             if isinstance(axes, tuple) and len(axes) > 1:
                 cx_all = cx_all.reshape((-1,) + cx_all.shape[2:])
                 cy_all = cy_all.reshape((-1,) + cy_all.shape[2:])
                 ws_all = ws_all.reshape(-1)
-            mix = W.mixture_weights(ws_all)
+            with step_scope("weight_sums"):
+                mix = W.mixture_weights(ws_all)
             if no_center:
                 # Only player 0 (the acting center) runs the ERM; the
                 # result is then broadcast from it.  lax.cond keeps the
@@ -283,13 +306,15 @@ def boost_attempt_sharded(mesh, cfg: BoostConfig, cls, num_rounds: int,
             else:
                 h, loss = _center_erm(cls, cx_all, cy_all, mix,
                                       cfg.coreset_size)
-            stuck_now = loss > cfg.weak_threshold
-            pred = cls.predict(h, xl)
-            new_hits = jnp.where(
-                stuck_now, hitsl,
-                W.update_hits(hitsl, pred == yl, al))
-            h_params = h_params.at[t].set(
-                jnp.where(stuck_now, h_params[t], h))
+            with step_scope("predict"):
+                pred = cls.predict(h, xl)
+            with step_scope("mw_update"):
+                stuck_now = loss > cfg.weak_threshold
+                new_hits = jnp.where(
+                    stuck_now, hitsl,
+                    W.update_hits(hitsl, pred == yl, al))
+                h_params = h_params.at[t].set(
+                    jnp.where(stuck_now, h_params[t], h))
             return (jnp.where(stuck_now, t, t + 1), it + 1, stuck_now,
                     new_hits, kkey, h_params, loss)
 

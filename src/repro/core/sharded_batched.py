@@ -72,6 +72,7 @@ from repro.core import streaming, weak
 from repro.core import weights as W
 from repro.core.pinned import pinned_argmax
 from repro.core.boost_attempt import _center_erm, _gather_coreset
+from repro.core.boost_attempt import step_scope
 from repro.core.types import BoostConfig
 from repro.obs import trace as obs_trace
 
@@ -125,52 +126,56 @@ def _round_body(cfg: BoostConfig, cls, k: int, x, y, alive, x_orders,
     # the round/step bodies there must land here too — the exact-parity
     # tests (tests/test_sharded_batched.py) fail on any divergence.
     kloc = x.shape[0]
-    key, kc = jax.random.split(c.key)
-    keys_all = jax.random.split(kc, k)    # the host loop's k-key stream
-    keys = _slice_player_keys(keys_all, kloc)
-    # --- players (local rows only): step 2(a) coreset + 2(b) sums ------
-    idx = jax.vmap(
-        lambda kk, xx, yy, hh, aa, oo, yso, aso:
-        approximation.select_coreset(
-            kk, xx if xx.ndim == 1 else xx[:, 0], yy, hh, aa,
-            cfg.coreset_size, cfg.deterministic_coreset and x.ndim == 2,
-            order=oo, y_sorted=yso, alive_sorted=aso)
-    )(keys, x, y, c.hits, alive, x_orders, y_sorted, alive_sorted)
-    cx, cy = _gather_coreset(x, y, idx)                   # [kloc, c(, F)]
-    log_wsums = jax.vmap(W.log_weight_sum)(c.hits, alive)  # [kloc]
-    if player_alive is not None:
-        # an absent player sends nothing: its weight sum leaves the
-        # mixture before the gather (−inf ⇒ mixture weight 0)
-        log_wsums = jnp.where(_local_player_mask(player_alive, kloc),
-                              log_wsums, -jnp.inf)
-    # --- the wire: every alive player's coreset + one scalar each ------
-    cx_all = jax.lax.all_gather(cx, AXIS)                 # [p, kloc, c(, F)]
-    cy_all = jax.lax.all_gather(cy, AXIS)
-    ws_all = jax.lax.all_gather(log_wsums, AXIS)          # [p, kloc]
+    # --- players (local rows only): step 2(a) coreset + 2(b) sums; the
+    # wire: every alive player's coreset + one scalar each --------------
+    with step_scope("coreset_draw"):
+        key, kc = jax.random.split(c.key)
+        keys_all = jax.random.split(kc, k)  # the host loop's k-key stream
+        keys = _slice_player_keys(keys_all, kloc)
+        idx = jax.vmap(
+            lambda kk, xx, yy, hh, aa, oo, yso, aso:
+            approximation.select_coreset(
+                kk, xx if xx.ndim == 1 else xx[:, 0], yy, hh, aa,
+                cfg.coreset_size,
+                cfg.deterministic_coreset and x.ndim == 2,
+                order=oo, y_sorted=yso, alive_sorted=aso)
+        )(keys, x, y, c.hits, alive, x_orders, y_sorted, alive_sorted)
+        cx, cy = _gather_coreset(x, y, idx)               # [kloc, c(, F)]
+        cx_all = jax.lax.all_gather(cx, AXIS)         # [p, kloc, c(, F)]
+        cy_all = jax.lax.all_gather(cy, AXIS)
+        cx_all = cx_all.reshape((k,) + cx_all.shape[2:])  # player order
+        cy_all = cy_all.reshape((k,) + cy_all.shape[2:])
+    with step_scope("weight_sums"):
+        log_wsums = jax.vmap(W.log_weight_sum)(c.hits, alive)  # [kloc]
+        if player_alive is not None:
+            # an absent player sends nothing: its weight sum leaves the
+            # mixture before the gather (−inf ⇒ mixture weight 0)
+            log_wsums = jnp.where(_local_player_mask(player_alive, kloc),
+                                  log_wsums, -jnp.inf)
+        ws_all = jax.lax.all_gather(log_wsums, AXIS).reshape(-1)   # [k]
+        mix = W.mixture_weights(ws_all)
     comm_mode = L.tree_comm_mode(cls)
     # payload counters: what alive players actually sent.  Unmasked,
     # they are taken from the gathered arrays themselves (move iff the
     # collective executed, by its actual size); masked, they charge the
     # per-player payload × the round's alive count.
-    k_alive = (jnp.int32(k) if player_alive is None
-               else jnp.sum(player_alive.astype(jnp.int32)))
-    core_pp_bytes = ((cx_all.size // k) * cx_all.dtype.itemsize
-                     + (cy_all.size // k) * cy_all.dtype.itemsize)
-    if comm_mode == "coreset":
-        if player_alive is None:
-            n_examples = int(np.prod(cy_all.shape))       # k · c, exactly
-            n_scalars = int(np.prod(ws_all.shape))        # k
-            n_bytes = (cx_all.size * cx_all.dtype.itemsize
-                       + cy_all.size * cy_all.dtype.itemsize
-                       + ws_all.size * ws_all.dtype.itemsize)
-        else:
-            n_examples = k_alive * cfg.coreset_size
-            n_scalars = k_alive
-            n_bytes = k_alive * (core_pp_bytes + ws_all.dtype.itemsize)
-    cx_all = cx_all.reshape((k,) + cx_all.shape[2:])      # player order
-    cy_all = cy_all.reshape((k,) + cy_all.shape[2:])
-    ws_all = ws_all.reshape(-1)
-    mix = W.mixture_weights(ws_all)
+    with step_scope("lane_state"):
+        k_alive = (jnp.int32(k) if player_alive is None
+                   else jnp.sum(player_alive.astype(jnp.int32)))
+        core_pp_bytes = ((cx_all.size // k) * cx_all.dtype.itemsize
+                         + (cy_all.size // k) * cy_all.dtype.itemsize)
+        if comm_mode == "coreset":
+            if player_alive is None:
+                n_examples = int(np.prod(cy_all.shape))   # k · c, exactly
+                n_scalars = int(np.prod(ws_all.shape))    # k
+                n_bytes = (cx_all.size * cx_all.dtype.itemsize
+                           + cy_all.size * cy_all.dtype.itemsize
+                           + ws_all.size * ws_all.dtype.itemsize)
+            else:
+                n_examples = k_alive * cfg.coreset_size
+                n_scalars = k_alive
+                n_bytes = k_alive * (core_pp_bytes
+                                     + ws_all.dtype.itemsize)
     # --- center: step 2(c)+(d) pooled weighted ERM ----------------------
     if comm_mode != "coreset":
         # Distributed tree growth: split finding runs on per-player
@@ -181,64 +186,76 @@ def _round_body(cfg: BoostConfig, cls, k: int, x, y, alive, x_orders,
         # below charge exactly that.  The merge is centerless by
         # construction (every device computes the identical merged
         # answer), so the §2.2 no_center flag is moot here.
-        pid = jax.lax.axis_index(AXIS)
-        mix_loc = jax.lax.dynamic_slice_in_dim(mix, pid * kloc, kloc, 0)
+        with step_scope("weight_sums"):
+            pid = jax.lax.axis_index(AXIS)
+            mix_loc = jax.lax.dynamic_slice_in_dim(mix, pid * kloc, kloc,
+                                                   0)
+            pw = W.erm_weights(mix_loc, cfg.coreset_size)
 
         def _ag(a):
             g = jax.lax.all_gather(a, AXIS)
             return g.reshape((k,) + g.shape[2:])
 
-        h, loss = cls.erm_players(cx, cy,
-                                  W.erm_weights(mix_loc, cfg.coreset_size),
-                                  all_gather=_ag)
+        with step_scope("center_erm"):
+            h, loss = cls.erm_players(cx, cy, pw, all_gather=_ag)
     elif no_center:
         # §2.2: the first ALIVE player acts as center; only its device
         # runs the ERM and the result is psum-broadcast back (exact:
         # all other summands are literal zeros).
-        pid = jax.lax.axis_index(AXIS)
-        center = (jnp.int32(0) if player_alive is None
-                  else pinned_argmax(player_alive))
-        cdev = center // kloc
-        h0, loss0 = jax.lax.cond(
-            pid == cdev,
-            lambda: _center_erm(cls, cx_all, cy_all, mix, cfg.coreset_size),
-            lambda: (jnp.zeros((weak.param_dim(cls),), jnp.float32),
-                     jnp.float32(0)))
-        h = jax.lax.psum(jnp.where(pid == cdev, h0, 0.0), AXIS)
-        loss = jax.lax.psum(jnp.where(pid == cdev, loss0, 0.0), AXIS)
+        with step_scope("center_erm"):
+            pid = jax.lax.axis_index(AXIS)
+            center = (jnp.int32(0) if player_alive is None
+                      else pinned_argmax(player_alive))
+            cdev = center // kloc
+            h0, loss0 = jax.lax.cond(
+                pid == cdev,
+                lambda: _center_erm(cls, cx_all, cy_all, mix,
+                                    cfg.coreset_size),
+                lambda: (jnp.zeros((weak.param_dim(cls),), jnp.float32),
+                         jnp.float32(0)))
+            h = jax.lax.psum(jnp.where(pid == cdev, h0, 0.0), AXIS)
+            loss = jax.lax.psum(jnp.where(pid == cdev, loss0, 0.0), AXIS)
     else:
         h, loss = _center_erm(cls, cx_all, cy_all, mix, cfg.coreset_size)
-    stuck_now = loss > cfg.weak_threshold
-    if comm_mode != "coreset":
-        # distributed-mode payloads: per-player scalar counts are
-        # STATIC class properties (ledger.py charges the same formulas)
-        # × the round's alive-player count; coreset examples move only
-        # when this round sticks (quarantine ships the points then)
-        hist_pp = L.hist_scalars_per_player(cls)
-        vote_pp = L.vote_entries_per_player(cls)
-        n_examples = jnp.where(stuck_now, k_alive * cfg.coreset_size, 0)
-        n_scalars = k_alive
-        n_hist = k_alive * hist_pp
-        n_votes = k_alive * vote_pp
-        n_bytes = (jnp.where(stuck_now, k_alive * core_pp_bytes, 0)
-                   + k_alive * (ws_all.dtype.itemsize
-                                + 4 * hist_pp      # f32 histogram cells
-                                + 4 * vote_pp))    # i32 vote entries
-    else:
-        n_hist = jnp.int32(0)
-        n_votes = jnp.int32(0)
+    with step_scope("mw_update"):
+        stuck_now = loss > cfg.weak_threshold
+    with step_scope("lane_state"):
+        if comm_mode != "coreset":
+            # distributed-mode payloads: per-player scalar counts are
+            # STATIC class properties (ledger.py charges the same
+            # formulas) × the round's alive-player count; coreset
+            # examples move only when this round sticks (quarantine
+            # ships the points then)
+            hist_pp = L.hist_scalars_per_player(cls)
+            vote_pp = L.vote_entries_per_player(cls)
+            n_examples = jnp.where(stuck_now,
+                                   k_alive * cfg.coreset_size, 0)
+            n_scalars = k_alive
+            n_hist = k_alive * hist_pp
+            n_votes = k_alive * vote_pp
+            n_bytes = (jnp.where(stuck_now, k_alive * core_pp_bytes, 0)
+                       + k_alive * (ws_all.dtype.itemsize
+                                    + 4 * hist_pp   # f32 histogram cells
+                                    + 4 * vote_pp))  # i32 vote entries
+        else:
+            n_hist = jnp.int32(0)
+            n_votes = jnp.int32(0)
     # --- players: step 2(f) multiplicative-weights update (local) ------
-    pred = cls.predict(h, x)
-    upd = W.update_hits(c.hits, pred == y, alive)
-    if player_alive is not None:
-        # absent players never received h_t: their MW state freezes
-        upd = jnp.where(_local_player_mask(player_alive, kloc)[:, None],
-                        upd, c.hits)
-    new_hits = jnp.where(stuck_now, c.hits, upd)
-    h_params = c.h_params.at[c.t].set(
-        jnp.where(stuck_now, c.h_params[c.t], h))
+    with step_scope("predict"):
+        pred = cls.predict(h, x)
+    with step_scope("mw_update"):
+        upd = W.update_hits(c.hits, pred == y, alive)
+        if player_alive is not None:
+            # absent players never received h_t: their MW state freezes
+            upd = jnp.where(
+                _local_player_mask(player_alive, kloc)[:, None], upd,
+                c.hits)
+        new_hits = jnp.where(stuck_now, c.hits, upd)
+        h_params = c.h_params.at[c.t].set(
+            jnp.where(stuck_now, c.h_params[c.t], h))
+        t = jnp.where(stuck_now, c.t, c.t + 1)
     return _RoundCarry(
-        t=jnp.where(stuck_now, c.t, c.t + 1),
+        t=t,
         it=c.it + 1,
         stuck=stuck_now,
         hits=new_hits,
@@ -332,33 +349,35 @@ def _one_step_sharded(cfg: BoostConfig, cls, k: int, no_center: bool,
     LOCKSTEP with batched._one_step (collectives replace lane pooling)."""
     a_max = cfg.opt_budget + 1
     kloc = x.shape[0]
-    active = (~s["done"]) & (s["attempt"] < a_max)
-    pa = sched[jnp.minimum(s["step"], sched.shape[0] - 1)]       # [k]
-    pa_loc = _local_player_mask(pa, kloc)
-    # ---- attempt start ------------------------------------------------
-    start = ~s["in_attempt"]
-    tkey = jax.random.wrap_key_data(s["key_data"])
-    nk, sub = jax.random.split(tkey)
-    key_data = jnp.where(start, jax.random.key_data(nk), s["key_data"])
-    akey_data = jnp.where(start, jax.random.key_data(sub),
-                          s["akey_data"])
-    m_alive = jax.lax.psum(
-        jnp.sum((s["alive"] & pa_loc[:, None]).astype(jnp.int32)), AXIS)
-    a = s["attempt"]
-    bound = jnp.where(start, batched.num_rounds_dynamic(cfg, m_alive),
-                      s["bound"])
-    hits = jnp.where(start, W.init_hits(x.shape[:2]), s["hits"])
-    cur_h = jnp.where(start, jnp.zeros_like(s["cur_h"]), s["cur_h"])
-    t = jnp.where(start, 0, s["t"])
-    awire_core = jnp.where(start, 0, s["awire_core"])
-    awire_ws = jnp.where(start, 0, s["awire_ws"])
-    awire_hist = jnp.where(start, 0, s["awire_hist"])
-    awire_votes = jnp.where(start, 0, s["awire_votes"])
-    hist_alive = jnp.where(start, s["hist_alive"].at[a].set(m_alive),
-                           s["hist_alive"])
+    with step_scope("lane_state"):
+        active = (~s["done"]) & (s["attempt"] < a_max)
+        pa = sched[jnp.minimum(s["step"], sched.shape[0] - 1)]       # [k]
+        pa_loc = _local_player_mask(pa, kloc)
+        # ---- attempt start ------------------------------------------------
+        start = ~s["in_attempt"]
+        tkey = jax.random.wrap_key_data(s["key_data"])
+        nk, sub = jax.random.split(tkey)
+        key_data = jnp.where(start, jax.random.key_data(nk), s["key_data"])
+        akey_data = jnp.where(start, jax.random.key_data(sub),
+                              s["akey_data"])
+        m_alive = jax.lax.psum(
+            jnp.sum((s["alive"] & pa_loc[:, None]).astype(jnp.int32)), AXIS)
+        a = s["attempt"]
+        bound = jnp.where(start, batched.num_rounds_dynamic(cfg, m_alive),
+                          s["bound"])
+        hits = jnp.where(start, W.init_hits(x.shape[:2]), s["hits"])
+        cur_h = jnp.where(start, jnp.zeros_like(s["cur_h"]), s["cur_h"])
+        t = jnp.where(start, 0, s["t"])
+        awire_core = jnp.where(start, 0, s["awire_core"])
+        awire_ws = jnp.where(start, 0, s["awire_ws"])
+        awire_hist = jnp.where(start, 0, s["awire_hist"])
+        awire_votes = jnp.where(start, 0, s["awire_votes"])
+        hist_alive = jnp.where(start, s["hist_alive"].at[a].set(m_alive),
+                               s["hist_alive"])
     # ---- one BoostAttempt round over the wire -------------------------
-    y_sorted = jnp.take_along_axis(y, x_orders, axis=1)
-    alive_sorted = jnp.take_along_axis(s["alive"], x_orders, axis=1)
+    with step_scope("sort_order"):
+        y_sorted = jnp.take_along_axis(y, x_orders, axis=1)
+        alive_sorted = jnp.take_along_axis(s["alive"], x_orders, axis=1)
     rc = _RoundCarry(
         t=t, it=jnp.int32(0), stuck=jnp.asarray(False),
         hits=hits, key=jax.random.wrap_key_data(akey_data),
@@ -369,62 +388,63 @@ def _one_step_sharded(cfg: BoostConfig, cls, k: int, no_center: bool,
         wire_votes=jnp.int32(0))
     out = _round_body(cfg, cls, k, x, y, s["alive"], x_orders, y_sorted,
                       alive_sorted, no_center, rc, player_alive=pa)
-    stuck = out.stuck
-    success = (~stuck) & (out.t >= bound)
-    ended = stuck | success
-    k_alive = jnp.sum(pa.astype(jnp.int32))
-    awire_core = awire_core + out.wire_core
-    awire_ws = awire_ws + out.wire_ws
-    awire_hist = awire_hist + out.wire_hist
-    awire_votes = awire_votes + out.wire_votes
-    nxt = {
-        "attempt": jnp.where(ended, a + 1, a),
-        "done": s["done"] | success,
-        "alive": s["alive"],
-        "disputed": s["disputed"],
-        "key_data": key_data,
-        "h_params": jnp.where(success, out.h_params, s["h_params"]),
-        "rounds": jnp.where(success, out.t, s["rounds"]),
-        "min_loss": out.min_loss,
-        "hist_stuck": jnp.where(ended, s["hist_stuck"].at[a].set(stuck),
-                                s["hist_stuck"]),
-        "hist_rounds": jnp.where(ended,
-                                 s["hist_rounds"].at[a].set(out.t),
-                                 s["hist_rounds"]),
-        "hist_alive": hist_alive,
-        "hist_p": s["hist_p"],
-        "hist_players": s["hist_players"].at[a].add(k_alive),
-        "hist_players_h": s["hist_players_h"].at[a].add(
-            jnp.where(stuck, 0, k_alive)),
-        "hist_players_last": s["hist_players_last"].at[a].set(k_alive),
-        "in_attempt": ~ended,
-        "akey_data": jax.random.key_data(out.key),
-        "t": out.t,
-        "bound": bound,
-        "hits": out.hits,
-        "cur_h": out.h_params,
-        "core_x": out.core_x, "core_y": out.core_y,
-        "step": s["step"] + 1,
-        "awire_core": awire_core, "awire_ws": awire_ws,
-        "awire_hist": awire_hist, "awire_votes": awire_votes,
-        "hist_wire_core": jnp.where(
-            ended, s["hist_wire_core"].at[a].set(awire_core),
-            s["hist_wire_core"]),
-        "hist_wire_ws": jnp.where(
-            ended, s["hist_wire_ws"].at[a].set(awire_ws),
-            s["hist_wire_ws"]),
-        "hist_wire_hist": jnp.where(
-            ended, s["hist_wire_hist"].at[a].set(awire_hist),
-            s["hist_wire_hist"]),
-        "hist_wire_votes": jnp.where(
-            ended, s["hist_wire_votes"].at[a].set(awire_votes),
-            s["hist_wire_votes"]),
-        "wire_bytes": s["wire_bytes"] + out.wire_bytes,
-        "wire_q_points": s["wire_q_points"],
-        "wire_q_counts": s["wire_q_counts"],
-    }
-    nxt = jax.tree_util.tree_map(
-        lambda new, old: jnp.where(active, new, old), nxt, s)
+    with step_scope("lane_state"):
+        stuck = out.stuck
+        success = (~stuck) & (out.t >= bound)
+        ended = stuck | success
+        k_alive = jnp.sum(pa.astype(jnp.int32))
+        awire_core = awire_core + out.wire_core
+        awire_ws = awire_ws + out.wire_ws
+        awire_hist = awire_hist + out.wire_hist
+        awire_votes = awire_votes + out.wire_votes
+        nxt = {
+            "attempt": jnp.where(ended, a + 1, a),
+            "done": s["done"] | success,
+            "alive": s["alive"],
+            "disputed": s["disputed"],
+            "key_data": key_data,
+            "h_params": jnp.where(success, out.h_params, s["h_params"]),
+            "rounds": jnp.where(success, out.t, s["rounds"]),
+            "min_loss": out.min_loss,
+            "hist_stuck": jnp.where(ended, s["hist_stuck"].at[a].set(stuck),
+                                    s["hist_stuck"]),
+            "hist_rounds": jnp.where(ended,
+                                     s["hist_rounds"].at[a].set(out.t),
+                                     s["hist_rounds"]),
+            "hist_alive": hist_alive,
+            "hist_p": s["hist_p"],
+            "hist_players": s["hist_players"].at[a].add(k_alive),
+            "hist_players_h": s["hist_players_h"].at[a].add(
+                jnp.where(stuck, 0, k_alive)),
+            "hist_players_last": s["hist_players_last"].at[a].set(k_alive),
+            "in_attempt": ~ended,
+            "akey_data": jax.random.key_data(out.key),
+            "t": out.t,
+            "bound": bound,
+            "hits": out.hits,
+            "cur_h": out.h_params,
+            "core_x": out.core_x, "core_y": out.core_y,
+            "step": s["step"] + 1,
+            "awire_core": awire_core, "awire_ws": awire_ws,
+            "awire_hist": awire_hist, "awire_votes": awire_votes,
+            "hist_wire_core": jnp.where(
+                ended, s["hist_wire_core"].at[a].set(awire_core),
+                s["hist_wire_core"]),
+            "hist_wire_ws": jnp.where(
+                ended, s["hist_wire_ws"].at[a].set(awire_ws),
+                s["hist_wire_ws"]),
+            "hist_wire_hist": jnp.where(
+                ended, s["hist_wire_hist"].at[a].set(awire_hist),
+                s["hist_wire_hist"]),
+            "hist_wire_votes": jnp.where(
+                ended, s["hist_wire_votes"].at[a].set(awire_votes),
+                s["hist_wire_votes"]),
+            "wire_bytes": s["wire_bytes"] + out.wire_bytes,
+            "wire_q_points": s["wire_q_points"],
+            "wire_q_counts": s["wire_q_counts"],
+        }
+        nxt = jax.tree_util.tree_map(
+            lambda new, old: jnp.where(active, new, old), nxt, s)
     return nxt, batched.Stuck(stuck & active, out.core_x, pa)
 
 
@@ -460,15 +480,17 @@ def _build_sharded_step(mesh: Mesh, cfg: BoostConfig, cls,
         x1d = x if x.ndim == 3 else x[..., 0]
         # chunk-local runs under cfg.chunk_size, bitwise identical to
         # the monolithic argsort (streaming tier)
-        x_orders = jax.vmap(jax.vmap(lambda v: streaming.sort_order(
-            v, cfg.chunk_size, cfg.domain_size)))(x1d)
+        with step_scope("sort_order"):
+            x_orders = jax.vmap(jax.vmap(lambda v: streaming.sort_order(
+                v, cfg.chunk_size, cfg.domain_size)))(x1d)
 
         def active(st):
             return (~st["done"]) & (st["attempt"] < a_max)
 
         def cond(carry):
             st, i = carry
-            return jnp.any(active(st)) & (i < n)
+            with step_scope("lane_state"):
+                return jnp.any(active(st)) & (i < n)
 
         def body(carry):
             st, i = carry
@@ -477,12 +499,14 @@ def _build_sharded_step(mesh: Mesh, cfg: BoostConfig, cls,
                 x, y, x_orders, sched, st)
             # the pooled loss is replicated, so every device takes the
             # same branch
-            st2 = jax.lax.cond(
-                jnp.any(stuck.stuck),
-                lambda st2: jax.vmap(functools.partial(
-                    _quarantine_lane, cfg))(x, st2, stuck),
-                lambda st2: st2, st2)
-            return st2, i + 1
+            with step_scope("quarantine"):
+                st2 = jax.lax.cond(
+                    jnp.any(stuck.stuck),
+                    lambda st2: jax.vmap(functools.partial(
+                        _quarantine_lane, cfg))(x, st2, stuck),
+                    lambda st2: st2, st2)
+            with step_scope("lane_state"):
+                return st2, i + 1
 
         out, _ = jax.lax.while_loop(cond, body, (state, jnp.int32(0)))
         return out
@@ -527,8 +551,7 @@ def run_rounds_sharded(state: dict, x, y, cfg: BoostConfig, cls,
     n_arr = batched._RUN_FOREVER if n is None else jnp.int32(n)
     with obs_trace.span("run_rounds", "engine", engine="sharded", B=B,
                         n=(-1 if n is None else int(n)),
-                        mesh_devices=int(mesh.shape[AXIS])), \
-            obs_trace.annotate("run_rounds_sharded"):
+                        mesh_devices=int(mesh.shape[AXIS])):
         return fn(x, y, sched, state, n_arr)
 
 
@@ -539,8 +562,9 @@ def _build_sharded(mesh: Mesh, cfg: BoostConfig, cls, t_buf: int,
     step = _build_sharded_step(mesh, cfg, cls, no_center)
 
     def full(x, y, alive, keys, sched):
-        state = init_state_sharded(x, y, keys, cfg, alive=alive,
-                                   t_buf=t_buf, cls=cls)
+        with step_scope("lane_state"):
+            state = init_state_sharded(x, y, keys, cfg, alive=alive,
+                                       t_buf=t_buf, cls=cls)
         return step(x, y, sched, state, batched._RUN_FOREVER)
 
     return jax.jit(full)
@@ -559,8 +583,9 @@ def lower_classify_sharded(x, y, alive, keys, cfg: BoostConfig, cls,
     fn = _build_sharded(mesh, cfg, cls, t_buf, no_center)
     with obs_trace.span("compile", "compile", engine="sharded",
                         B=int(x.shape[0]), mloc=int(x.shape[2])):
-        return fn.lower(jnp.asarray(x), jnp.asarray(y),
-                        jnp.asarray(alive), keys, sched).compile()
+        return obs_trace.compile_published(
+            fn, jnp.asarray(x), jnp.asarray(y), jnp.asarray(alive), keys,
+            sched)
 
 
 @dataclasses.dataclass
@@ -692,8 +717,10 @@ def finalize_sharded(state: dict, x, y, alive0, cfg: BoostConfig, cls,
     ``hist_wire_*``, [B] int32 ``wire_*``) that
     ``validate_ledger`` checks against the Theorem 4.1 accounting
     (docs/ledger.md).  Pure materialisation, no protocol math."""
-    with obs_trace.span("finalize", "engine", engine="sharded"):
+    with obs_trace.span("finalize.state", "engine", engine="sharded"):
         out = jax.device_get(state)
+    with obs_trace.span("finalize.inputs", "engine", engine="sharded"):
+        x, y, alive0 = np.asarray(x), np.asarray(y), np.asarray(alive0)
     return ShardedClassifyResult(
         hypotheses=out["h_params"], rounds=out["rounds"],
         ok=np.asarray(out["done"]), attempts=out["attempt"],
@@ -701,8 +728,7 @@ def finalize_sharded(state: dict, x, y, alive0, cfg: BoostConfig, cls,
         min_loss=out["min_loss"],
         hist_stuck=out["hist_stuck"], hist_rounds=out["hist_rounds"],
         hist_alive=out["hist_alive"], hist_p=out["hist_p"],
-        x=np.asarray(x), y=np.asarray(y), alive0=np.asarray(alive0),
-        cfg=cfg, cls=cls,
+        x=x, y=y, alive0=alive0, cfg=cfg, cls=cls,
         m_true=None if m_true is None else np.asarray(m_true),
         hist_players=out["hist_players"],
         hist_players_h=out["hist_players_h"],

@@ -194,8 +194,8 @@ def test_monkeypatched_unpin_fails_jaxpr_audit(monkeypatch):
 
 
 @pytest.mark.parametrize("deleted", [
-    "    awire_core = awire_core + out.wire_core\n",
-    "    awire_ws = awire_ws + out.wire_ws\n",
+    "        awire_core = awire_core + out.wire_core\n",
+    "        awire_ws = awire_ws + out.wire_ws\n",
 ])
 def test_deleting_wire_accumulation_fails_rl002(deleted):
     rel = "src/repro/core/sharded_batched.py"

@@ -16,6 +16,7 @@ every such rehearsal in this one file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -160,3 +161,30 @@ def test_sharded_engine_compiles_on_a_2x2_mesh(topo):
                                         False)
     text = fn.lower(*args).compile().as_text()
     assert "all-gather" in text
+
+
+def test_tree_engine_for_v5e_maps_its_ops_to_steps(one_chip, monkeypatch):
+    """The round steps survive the TPU compiler: every device op inside
+    the protocol loop of the tree engine compiled for a v5e maps to a
+    step, and the histogram kernel's op, found by its name, to
+    ``center_erm``.  B = 1 task of 2^12 points at the deployment's
+    widths."""
+    from repro.obs import trace as obs_trace
+    monkeypatch.setattr(hist_ops, "_on_tpu", lambda: True)
+    cls, cfg = _tree_deployment()
+    m = 1 << 12
+    args = _engine_args(cfg, 1, m, 28, one_chip)
+    text = batched._classify_batched_jit.lower(
+        *args, cfg, cls, cfg.num_rounds(m)).compile().as_text()
+    steps = obs_trace.hlo_steps(text)
+    kernel = {s for n, s in steps.items()
+              if n.startswith("hist_batched_pallas")}
+    assert kernel == {"center_erm"}
+    assert set(obs_trace.ROUND_STEPS) - set(steps.values()) == {
+        "sort_order"}
+    comps = obs_trace.parse_hlo(text)
+    body = [name for c in re.findall(r"\bbody=%?([\w.\-]+)", text)
+            for name, op, *_ in comps[c]
+            if op not in ("parameter", "get-tuple-element", "tuple",
+                          "constant", "bitcast")]
+    assert body and all(steps[n] is not None for n in body)

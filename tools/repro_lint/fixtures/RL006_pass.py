@@ -22,7 +22,6 @@ def _run(s):
 
 
 def run(s):
-    """Host wrapper: span + annotation OUTSIDE the traced closure."""
-    with obs_trace.span("run_rounds", "engine", engine="fixture"), \
-            obs_trace.annotate("run"):
+    """Host wrapper: the span OUTSIDE the traced closure."""
+    with obs_trace.span("run_rounds", "engine", engine="fixture"):
         return _run(jnp.asarray(s, jnp.float32))
