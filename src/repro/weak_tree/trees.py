@@ -56,6 +56,44 @@ from repro.kernels.histogram import ops as H
 TYPE_TREE = 5.0
 
 
+def _select(idx, values):
+    """``values[idx]`` for ``idx`` in [0, len(values)), as a chain of
+    selects over the static ids: no row indexes a table, so the device
+    runs no per-row gather."""
+    out = values[0]
+    for i in range(1, len(values)):
+        out = jnp.where(idx == i, values[i], out)
+    return out
+
+
+def descend(b, node, feat, qbin):
+    """One level of the level-order descent.
+
+    b [..., F] int32 bin ids; node [...] int32, each row's node among
+    the level's N; feat, qbin [N] the level's splits → the child ids
+    node·2 + 1[b[feat[node]] ≥ qbin[node]] in [0, 2N).  Each node's
+    test reads its feature by an iota compare over the feature axis
+    (exact in int32) and broadcasts only the node's scalars; the row's
+    own test is then selected among the N — nothing gathers per row.
+    """
+    fid = jnp.arange(b.shape[-1], dtype=jnp.int32)
+    bits = [(jnp.sum(jnp.where(fid == feat[i], b, jnp.int32(0)), axis=-1,
+                     dtype=jnp.int32) >= qbin[i]).astype(jnp.int32)
+            for i in range(feat.shape[0])]
+    return node * 2 + _select(node, bits)
+
+
+def route(b, feat, qbin):
+    """b [..., F] int32 bin ids, feat/qbin [2^L − 1] the first L levels
+    of a tree in level order → each row's node on level L, [...] int32
+    in [0, 2^L): the leaf when L is the tree's depth."""
+    node = jnp.zeros(b.shape[:-1], jnp.int32)
+    for level in range((feat.shape[0] + 1).bit_length() - 1):
+        lo, hi = (1 << level) - 1, (2 << level) - 1
+        node = descend(b, node, feat[lo:hi], qbin[lo:hi])
+    return node
+
+
 @dataclasses.dataclass(frozen=True)
 class HistogramTrees:
     """H = depth-``depth`` axis trees over [0,1)^F on a ``bins``-bin
@@ -165,23 +203,11 @@ class HistogramTrees:
         sign = p[1 + 2 * ni:1 + 2 * ni + self.leaves]
         return feat, qbin, sign
 
-    def _route(self, feat, qbin, b):
-        """b [M, F] bin ids → leaf index [M] (level-order descent)."""
-        node = jnp.zeros(b.shape[:-1], jnp.int32)
-        for level in range(self.depth):
-            flat = node + ((1 << level) - 1)
-            f = feat[flat]
-            q = qbin[flat]
-            xv = jnp.take_along_axis(b, f[..., None], axis=-1)[..., 0]
-            node = node * 2 + (xv >= q).astype(jnp.int32)
-        return node
-
     def _predict_one(self, p: jax.Array, x: jax.Array) -> jax.Array:
         feat, qbin, sign = self._unpack(p)
-        b = H.bin_index(x, self.bins)
-        leaf = self._route(feat, qbin, b)
-        return jnp.where(jnp.take(sign, leaf) > 0,
-                         jnp.int8(1), jnp.int8(-1))
+        leaf = route(H.bin_index(x, self.bins), feat, qbin)
+        s = _select(leaf, [sign[i] for i in range(self.leaves)])
+        return jnp.where(s > 0, jnp.int8(1), jnp.int8(-1))
 
     def predict(self, params: jax.Array, x: jax.Array) -> jax.Array:
         """params [..., P], x [*pts, F] → int8 ±1 [*param_batch, *pts]."""
@@ -207,11 +233,11 @@ class HistogramTrees:
         c = xs.shape[0]
         wy = w * ys.astype(w.dtype)
         b = H.bin_index(xs, self.bins)
-        route = jnp.zeros((c,), jnp.int32)
+        node = jnp.zeros((c,), jnp.int32)
         feats, qbins = [], []
         for level in range(self.depth):
             N = 1 << level
-            onnode = (route[:, None]
+            onnode = (node[:, None]
                       == jnp.arange(N, dtype=jnp.int32)[None])    # [c, N]
             wn = jnp.where(onnode, w[:, None], 0.0).T             # [N, c]
             wyn = jnp.where(onnode, wy[:, None], 0.0).T
@@ -219,12 +245,9 @@ class HistogramTrees:
                                              chunk_size=self.chunk_size)
             feats.append(f_n)
             qbins.append(q_n)
-            f_pt = f_n[route]
-            q_pt = q_n[route]
-            xv = jnp.take_along_axis(b, f_pt[:, None], axis=1)[:, 0]
-            route = route * 2 + (xv >= q_pt).astype(jnp.int32)
+            node = descend(b, node, f_n, q_n)
         NL = self.leaves
-        onleaf = (route[:, None] == jnp.arange(NL, dtype=jnp.int32)[None])
+        onleaf = (node[:, None] == jnp.arange(NL, dtype=jnp.int32)[None])
         w_leaf = jnp.sum(jnp.where(onleaf, w[:, None], 0.0), axis=0)
         wy_leaf = jnp.sum(jnp.where(onleaf, wy[:, None], 0.0), axis=0)
         sign = jnp.where(wy_leaf >= 0, 1.0, -1.0)    # sign(0) := +1
@@ -285,12 +308,12 @@ class HistogramTrees:
         w = jnp.broadcast_to(pw[:, None], (kp, c))            # [kp, c]
         wy = w * cy.astype(w.dtype)
         b = H.bin_index(cx, self.bins)                        # [kp, c, F]
-        route = jnp.zeros((kp, c), jnp.int32)
+        node = jnp.zeros((kp, c), jnp.int32)
         feats, qbins = [], []
         sel = q_n = hw_m = hwy_m = None
         for level in range(self.depth):
             N = 1 << level
-            onnode = (route[..., None]
+            onnode = (node[..., None]
                       == jnp.arange(N, dtype=jnp.int32))      # [kp, c, N]
             wn = jnp.where(onnode, w[..., None], 0.0)
             wyn = jnp.where(onnode, wy[..., None], 0.0)
@@ -327,14 +350,11 @@ class HistogramTrees:
                 sel = f_n
             feats.append(f_n)
             qbins.append(q_n)
-            f_pt = f_n[route]
-            q_pt = q_n[route]
-            xv = jnp.take_along_axis(b, f_pt[..., None], axis=-1)[..., 0]
-            route = route * 2 + (xv >= q_pt).astype(jnp.int32)
+            node = descend(b, node, f_n, q_n)
         # -- leaves from the last level's merged histograms: the chosen
         # column's prefix sums at q give each child's (w, wy) exactly —
         # children interleave as [left_0, right_0, left_1, …], matching
-        # the route*2 + (bin ≥ q) descent above.
+        # the node·2 + (bin ≥ q) descent above.
         hw_sel = jnp.take_along_axis(
             hw_m, sel[:, None, None], axis=1)[:, 0]           # [N, Q]
         hwy_sel = jnp.take_along_axis(hwy_m, sel[:, None, None],
