@@ -33,7 +33,8 @@ scope in every instruction's ``op_name``.  The engines'
 ``lower_classify*`` publish the executables they return
 (:func:`publish_program`, held weakly), and :func:`op_steps` maps
 each instruction name of their optimized HLO to its step — the names
-a device trace gives its ops.
+a device trace gives its ops.  :func:`op_parts` maps them, the same
+way, to the parts of the center's ERM (:data:`ERM_PARTS`).
 """
 
 from __future__ import annotations
@@ -60,6 +61,12 @@ _capturing = _profiler.TraceMe.is_enabled
 # core/sharded_batched.py); docs/observability.md maps each to its code.
 ROUND_STEPS = ("sort_order", "coreset_draw", "weight_sums", "center_erm",
                "predict", "mw_update", "lane_state", "quarantine")
+
+# Parts of the center's ERM, each a ``jax.named_scope`` nested in
+# ``center_erm`` (weak_tree/trees.py ``HistogramTrees.erm_players``): the
+# parties' histogram merge and the split search.  Not steps: the step
+# map reads only ROUND_STEPS, so an op keeps its step.
+ERM_PARTS = ("hist_merge", "split_search")
 
 
 # Ledger-category ↔ Ledger-field mapping: the ``task_bits`` dicts that
@@ -294,15 +301,16 @@ _BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}")
 _WRAPPED = re.compile(r"^[\w.\-]*\((.*)\)$")
 
 
-def _scope_step(op_name: str) -> str | None:
-    """The innermost component of an ``op_name`` that is a step."""
+def _scope_step(op_name: str, names=ROUND_STEPS) -> str | None:
+    """The innermost component of an ``op_name`` that is one of
+    ``names`` (a step by default)."""
     for part in reversed(op_name.split("/")):
         while True:
             m = _WRAPPED.match(part)
             if m is None:
                 break
             part = m.group(1)
-        if part in ROUND_STEPS:
+        if part in names:
             return part
     return None
 
@@ -318,10 +326,11 @@ def _operands(rest: str, op) -> list:
     return _OPERAND.findall(rest, op.end(), end)
 
 
-def parse_hlo(text: str) -> dict:
-    """{computation: [(instruction, opcode, step of its own op_name or
-    None, [computations it calls], [its operands])]} of an HLO module's
-    text, each computation's instructions in program order."""
+def parse_hlo(text: str, names=ROUND_STEPS) -> dict:
+    """{computation: [(instruction, opcode, scope of ``names`` (a
+    step by default) in its own op_name or None, [computations it
+    calls], [its operands])]} of an HLO module's text, each
+    computation's instructions in program order."""
     comps: dict = {}
     body = None
     for line in text.splitlines():
@@ -334,7 +343,8 @@ def parse_hlo(text: str) -> dict:
             for group in _BRANCHES.findall(rest):
                 called += [c.strip().lstrip("%") for c in group.split(",")]
             body.append((m.group(1), op.group(1) if op else "",
-                         _scope_step(name.group(1)) if name else None,
+                         _scope_step(name.group(1), names)
+                         if name else None,
                          called, _operands(rest, op)))
             continue
         m = _COMPUTATION.match(line)
@@ -343,12 +353,59 @@ def parse_hlo(text: str) -> dict:
     return comps
 
 
-def _majority(steps) -> str | None:
-    """The most frequent step (ties: the earlier of ROUND_STEPS)."""
+def _majority(steps, names=ROUND_STEPS) -> str | None:
+    """The most frequent step (ties: the earlier of ``names``)."""
     count = collections.Counter(s for s in steps if s is not None)
     if not count:
         return None
-    return max(count, key=lambda s: (count[s], -ROUND_STEPS.index(s)))
+    return max(count, key=lambda s: (count[s], -names.index(s)))
+
+
+# opcodes that only move values around: no vote on a fusion's part
+_STRUCTURAL = ("parameter", "constant", "tuple", "get-tuple-element",
+               "bitcast")
+
+
+def _scope_map(text: str, names, loose: bool) -> dict:
+    """{instruction name: one of ``names`` or None}: the innermost of
+    ``names`` in the instruction's ``op_name``; else, over the
+    computations it calls, the majority scope (``loose``) or the scope
+    of more than half of their ops but the structural ones (strict);
+    else (``loose`` only) the majority scope of its users, else of its
+    operands."""
+    comps = parse_hlo(text, names)
+    votes: dict = {}
+
+    def called_scope(called) -> str | None:
+        scopes = [s for c in called
+                  for i, s in zip(comps.get(c, ()), comp_scopes(c))
+                  if loose or i[1] not in _STRUCTURAL]
+        top = _majority(scopes, names)
+        if loose or scopes.count(top) * 2 > len(scopes):
+            return top
+        return None
+
+    def comp_scopes(c) -> list:
+        if c not in votes:
+            votes[c] = []                       # a cycle reads empty
+            votes[c] = [scope or called_scope(called)
+                        for _, _, scope, called, _ in comps.get(c, ())]
+        return votes[c]
+
+    out = {}
+    for c, instrs in comps.items():
+        own = dict(zip((i[0] for i in instrs), comp_scopes(c)))
+        users = collections.defaultdict(list)
+        for name, *_, operands in instrs:
+            for o in operands:
+                users[o].append(own.get(name))
+        for name, *_, operands in instrs:
+            out[name] = own[name]
+            if loose and out[name] is None:
+                out[name] = (_majority(users[name], names)
+                             or _majority((out.get(o) for o in operands),
+                                          names))
+    return out
 
 
 def hlo_steps(text: str) -> dict:
@@ -363,58 +420,57 @@ def hlo_steps(text: str) -> dict:
     unscoped (None).  Ties go to the earlier step of
     :data:`ROUND_STEPS`.
     """
-    comps = parse_hlo(text)
-    votes: dict = {}
+    return _scope_map(text, ROUND_STEPS, loose=True)
 
-    def called_step(called) -> str | None:
-        return _majority(s for c in called for s in comp_steps(c))
 
-    def comp_steps(c) -> list:
-        if c not in votes:
-            votes[c] = []                       # a cycle reads empty
-            votes[c] = [step or called_step(called)
-                        for _, _, step, called, _ in comps.get(c, ())]
-        return votes[c]
+def hlo_parts(text: str) -> dict:
+    """{instruction name: part of the center's ERM or None} of an HLO
+    module's text.
 
-    out = {}
-    for c, instrs in comps.items():
-        own = dict(zip((i[0] for i in instrs), comp_steps(c)))
-        users = collections.defaultdict(list)
-        for name, *_, operands in instrs:
-            for o in operands:
-                users[o].append(own.get(name))
-        for name, *_, operands in instrs:
-            out[name] = (own[name] or _majority(users[name])
-                         or _majority(out.get(o) for o in operands))
-    return out
+    An instruction's part is the innermost part of its ``op_name``.
+    One with none takes the part of more than half of the ops (all but
+    parameters, constants, tuples and bitcasts; an op in no part is a
+    vote for none) of the computations it calls: a fusion mostly of the
+    merge is the merge, and a loop whose body is mostly outside the ERM
+    is in no part.  Never from its users or operands: the kernel that
+    feeds the merge is no part of it."""
+    return _scope_map(text, ERM_PARTS, loose=False)
 
 
 class _Program:
     """A published executable's program, held only while its owner
-    holds the executable, and its instruction → step map once built."""
+    holds the executable, and its instruction → step and → part maps
+    once built."""
 
-    __slots__ = ("program", "steps")
+    __slots__ = ("program", "steps", "parts")
 
     def __init__(self, program):
         self.program = program
-        self.steps = None
+        self.steps = self.parts = None
 
     def build(self) -> dict:
         if self.steps is None:
-            self.steps = hlo_steps(self.program.as_text() or "")
+            text = self.program.as_text() or ""
+            self.steps, self.parts = hlo_steps(text), hlo_parts(text)
         return self.steps
+
+    def build_parts(self) -> dict:
+        self.build()
+        return self.parts
 
 
 _PUBLISHED: list = []
 # the maps of dropped programs, built as each was dropped (the newest)
 _DROPPED: collections.deque = collections.deque(maxlen=16)
+_DROPPED_PARTS: collections.deque = collections.deque(maxlen=16)
 
 
 def publish_program(compiled) -> None:
-    """Publish a ``jax.stages.Compiled`` for :func:`op_steps`, weakly:
-    the caller still owns it, and dropping it frees the program (a
-    compile-cache eviction relies on that).  Its map is built when
-    first read, or as it is dropped, whichever comes first."""
+    """Publish a ``jax.stages.Compiled`` for :func:`op_steps` and
+    :func:`op_parts`, weakly: the caller still owns it, and dropping it
+    frees the program (a compile-cache eviction relies on that).  Its
+    maps are built when first read, or as it is dropped, whichever
+    comes first."""
     prog = _Program(compiled._executable)
     _PUBLISHED.append(prog)
     weakref.finalize(compiled, _dropped, prog).atexit = False
@@ -423,10 +479,24 @@ def publish_program(compiled) -> None:
 def _dropped(prog: _Program) -> None:
     try:
         _DROPPED.append(prog.build())
+        _DROPPED_PARTS.append(prog.parts)
     finally:
         prog.program = None
         if prog in _PUBLISHED:
             _PUBLISHED.remove(prog)
+
+
+def _merged(maps) -> dict:
+    """The maps merged; a None entry, or a name two maps map
+    differently, is left out."""
+    merged: dict = {}
+    clash: set = set()
+    for one in maps:
+        for name, scope in one.items():
+            if merged.setdefault(name, scope) != scope:
+                clash.add(name)
+    return {name: scope for name, scope in merged.items()
+            if scope is not None and name not in clash}
 
 
 def op_steps() -> dict:
@@ -434,14 +504,16 @@ def op_steps() -> dict:
     program, built on first read (never while a program runs).  An
     instruction with no step, or one that two programs map differently,
     is left out: a device op of that name is unscoped."""
-    merged: dict = {}
-    clash: set = set()
-    for steps in [p.build() for p in list(_PUBLISHED)] + list(_DROPPED):
-        for name, step in steps.items():
-            if merged.setdefault(name, step) != step:
-                clash.add(name)
-    return {name: step for name, step in merged.items()
-            if step is not None and name not in clash}
+    return _merged([p.build() for p in list(_PUBLISHED)] + list(_DROPPED))
+
+
+def op_parts() -> dict:
+    """{HLO instruction name: part of the center's ERM}
+    (:data:`ERM_PARTS`) over every published program, as
+    :func:`op_steps` maps steps: an instruction in no part, or in
+    parts that differ between programs, is left out."""
+    return _merged([p.build_parts() for p in list(_PUBLISHED)]
+                   + list(_DROPPED_PARTS))
 
 
 def compile_published(jitted, *args):

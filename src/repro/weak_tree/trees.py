@@ -338,15 +338,19 @@ class HistogramTrees:
                 gidx = elect[None, :, :, None]
                 hw_e = jnp.take_along_axis(hw, gidx, axis=2)
                 hwy_e = jnp.take_along_axis(hwy, gidx, axis=2)
-                hw_m = jnp.sum(ag(hw_e), axis=0)              # [N, E, Q]
-                hwy_m = jnp.sum(ag(hwy_e), axis=0)
-                sel, q_n, _ = H.best_splits_ref(hw_m, hwy_m)
+                with jax.named_scope("hist_merge"):
+                    hw_m = jnp.sum(ag(hw_e), axis=0)          # [N, E, Q]
+                    hwy_m = jnp.sum(ag(hwy_e), axis=0)
+                with jax.named_scope("split_search"):
+                    sel, q_n, _ = H.best_splits_ref(hw_m, hwy_m)
                 f_n = jnp.take_along_axis(elect, sel[:, None],
                                           axis=1)[:, 0]
             else:                                             # histogram
-                hw_m = jnp.sum(ag(hw), axis=0)                # [N, F, Q]
-                hwy_m = jnp.sum(ag(hwy), axis=0)
-                f_n, q_n, _ = H.best_splits_ref(hw_m, hwy_m)
+                with jax.named_scope("hist_merge"):
+                    hw_m = jnp.sum(ag(hw), axis=0)            # [N, F, Q]
+                    hwy_m = jnp.sum(ag(hwy), axis=0)
+                with jax.named_scope("split_search"):
+                    f_n, q_n, _ = H.best_splits_ref(hw_m, hwy_m)
                 sel = f_n
             feats.append(f_n)
             qbins.append(q_n)
@@ -355,20 +359,21 @@ class HistogramTrees:
         # column's prefix sums at q give each child's (w, wy) exactly —
         # children interleave as [left_0, right_0, left_1, …], matching
         # the node·2 + (bin ≥ q) descent above.
-        hw_sel = jnp.take_along_axis(
-            hw_m, sel[:, None, None], axis=1)[:, 0]           # [N, Q]
-        hwy_sel = jnp.take_along_axis(hwy_m, sel[:, None, None],
-                                      axis=1)[:, 0]
-        cw = jnp.cumsum(hw_sel, axis=-1)
-        cwy = jnp.cumsum(hwy_sel, axis=-1)
-        left_w = jnp.take_along_axis(cw - hw_sel, q_n[:, None],
-                                     axis=-1)[:, 0]
-        left_wy = jnp.take_along_axis(cwy - hwy_sel, q_n[:, None],
-                                      axis=-1)[:, 0]
-        w_leaf = jnp.stack([left_w, cw[:, -1] - left_w],
-                           axis=-1).reshape(-1)
-        wy_leaf = jnp.stack([left_wy, cwy[:, -1] - left_wy],
-                            axis=-1).reshape(-1)
+        with jax.named_scope("split_search"):
+            hw_sel = jnp.take_along_axis(
+                hw_m, sel[:, None, None], axis=1)[:, 0]       # [N, Q]
+            hwy_sel = jnp.take_along_axis(hwy_m, sel[:, None, None],
+                                          axis=1)[:, 0]
+            cw = jnp.cumsum(hw_sel, axis=-1)
+            cwy = jnp.cumsum(hwy_sel, axis=-1)
+            left_w = jnp.take_along_axis(cw - hw_sel, q_n[:, None],
+                                         axis=-1)[:, 0]
+            left_wy = jnp.take_along_axis(cwy - hwy_sel, q_n[:, None],
+                                          axis=-1)[:, 0]
+            w_leaf = jnp.stack([left_w, cw[:, -1] - left_w],
+                               axis=-1).reshape(-1)
+            wy_leaf = jnp.stack([left_wy, cwy[:, -1] - left_wy],
+                                axis=-1).reshape(-1)
         sign = jnp.where(wy_leaf >= 0, 1.0, -1.0)    # sign(0) := +1
         loss = jnp.sum(0.5 * (w_leaf - jnp.abs(wy_leaf)))
         params = jnp.concatenate(

@@ -86,6 +86,7 @@ def own_registry(monkeypatch):
     """A registry of published programs of this test alone."""
     monkeypatch.setattr(T, "_PUBLISHED", [])
     monkeypatch.setattr(T, "_DROPPED", collections.deque(maxlen=16))
+    monkeypatch.setattr(T, "_DROPPED_PARTS", collections.deque(maxlen=16))
 
 
 def _while_body_ops(text):
@@ -253,3 +254,97 @@ def test_spans_land_on_the_profiler_clock(tmp_path, recorder):
     for name in ("finalize.state", "finalize.inputs"):
         (_, s0, s1), = [e for e in events if e[0] == name]
         assert w0 <= s0 <= s1 <= w1, name
+
+
+# -- parts of the center's ERM (ERM_PARTS) ---------------------------------
+
+@pytest.mark.parametrize("engine", ["batched", "sharded"])
+def test_compiled_tree_engine_maps_the_erm_parts(hlo, engine):
+    """Both parts are found, every op in a part is in the center's ERM,
+    and no loop or kernel-side op is taken into one."""
+    text = hlo["tree", engine]
+    parts, steps = T.hlo_parts(text), T.hlo_steps(text)
+    assert set(parts.values()) == {None, *T.ERM_PARTS}
+    assert {steps[n] for n, p in parts.items() if p} == {"center_erm"}
+    ops = {name: op for instrs in T.parse_hlo(text).values()
+           for name, op, *_ in instrs}
+    assert not [n for n, p in parts.items()
+                if p and ops[n] in ("while", "conditional", "call")]
+
+
+def test_erm_parts_add_no_instruction_and_move_no_step(hlo, fresh_traces,
+                                                       monkeypatch):
+    """With the two parts' scopes a no-op, the compiled tree engine is
+    the same program, and every instruction keeps its step."""
+    real = jax.named_scope
+
+    def scope(name):
+        return contextlib.nullcontext() if name in T.ERM_PARTS else real(
+            name)
+
+    monkeypatch.setattr(jax, "named_scope", scope)
+    bare = _lower("tree").as_text()
+    assert set(T.hlo_parts(bare).values()) == {None}
+    assert _no_metadata(bare) == _no_metadata(hlo["tree", "batched"])
+
+    def program(text):                  # without the source-file tables
+        return text.split("\n\nFileNames", 1)[0]
+
+    assert T.hlo_steps(program(bare)) == T.hlo_steps(
+        program(hlo["tree", "batched"]))
+
+
+def test_hlo_parts_rules():
+    text = """HloModule m
+
+%merge (p: f32[4], q: f32[4]) -> f32[4] {
+  %p = f32[4]{0} parameter(0)
+  %q = f32[4]{0} parameter(1)
+  %a = f32[4]{0} add(%p, %q), metadata={op_name="jit(f)/center_erm/vmap(hist_merge)/add"}
+  ROOT %b = f32[4]{0} multiply(%a, %a), metadata={op_name="jit(f)/center_erm/hist_merge/mul"}
+}
+
+%half (p: f32[4]) -> f32[4] {
+  %p = f32[4]{0} parameter(0)
+  %c = f32[4]{0} negate(%p), metadata={op_name="jit(f)/center_erm/split_search/neg"}
+  ROOT %d = f32[4]{0} abs(%c), metadata={op_name="jit(f)/center_erm/abs"}
+}
+
+ENTRY %main (x: f32[4]) -> (f32[4], f32[4]) {
+  %x = f32[4]{0} parameter(0)
+  %k = f32[4]{0} custom-call(%x), custom_call_target="tpu_custom_call", metadata={op_name="jit(f)/center_erm/pallas_call"}
+  %fusion.1 = f32[4]{0} fusion(%k, %k), kind=kLoop, calls=%merge
+  %fusion.2 = f32[4]{0} fusion(%fusion.1), kind=kLoop, calls=%half
+  %fusion.3 = f32[4]{0} fusion(%k, %x), kind=kLoop, calls=%merge, metadata={op_name="jit(f)/center_erm/split_search/hist_merge/x"}
+  %e = f32[4]{0} sine(%fusion.3), metadata={op_name="jit(f)/predict/sin"}
+  ROOT %t = (f32[4]{0}, f32[4]{0}) tuple(%fusion.2, %e)
+}
+"""
+    parts = T.hlo_parts(text)
+    assert parts["a"] == parts["b"] == "hist_merge"
+    assert parts["fusion.1"] == "hist_merge"   # all of its ops
+    assert parts["fusion.2"] is None           # half of its ops
+    assert parts["fusion.3"] == "hist_merge"   # the innermost part
+    assert parts["k"] is None                  # feeds the merge: no part
+    assert parts["e"] is None and parts["t"] is None
+    # the steps: every one of these ops is in the center's ERM
+    steps = T.hlo_steps(text)
+    assert {steps[n] for n in ("a", "k", "fusion.1", "fusion.3")} == {
+        "center_erm"}
+
+
+def test_op_parts_leave_clashing_names_out(own_registry):
+    T._DROPPED_PARTS.extend([{"fusion.1": "hist_merge",
+                              "fusion.2": "split_search", "copy.3": None},
+                             {"fusion.1": "hist_merge",
+                              "fusion.2": "hist_merge"}])
+    assert T.op_parts() == {"fusion.1": "hist_merge"}
+
+
+def test_published_program_publishes_its_parts(own_registry):
+    compiled = _lower("tree")
+    parts = {n: p for n, p in T.hlo_parts(compiled.as_text()).items() if p}
+    assert parts and T.op_parts() == parts
+    del compiled
+    gc.collect()
+    assert T.op_parts() == parts               # taken as it was dropped

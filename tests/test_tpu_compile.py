@@ -188,3 +188,57 @@ def test_tree_engine_for_v5e_maps_its_ops_to_steps(one_chip, monkeypatch):
             if op not in ("parameter", "get-tuple-element", "tuple",
                           "constant", "bitcast")]
     assert body and all(steps[n] is not None for n in body)
+
+
+# -- the Epsilon-width tree deployment (chip_bench trees-f2k-q256) ---------
+
+EPS_F, EPS_Q, EPS_C = 2000, 256, 1024
+
+
+@pytest.mark.parametrize("N", [1, 2])
+def test_histogram_kernel_compiles_at_epsilon_width(one_chip, N):
+    """The 16 parties of one task at 2,000 features on 256 bins: the
+    feature-blocked regime, 250 blocks of 8 features a launch (one
+    block held every feature at HIGGS's width)."""
+    assert HK.feature_block(EPS_F, EPS_Q) == (8, EPS_F)
+    c = 16 * EPS_C
+    x = _spec((16, c, EPS_F), jnp.float32, one_chip)
+    w = _spec((16, N, c), jnp.float32, one_chip)
+    compiled = jax.jit(lambda x, w, wy: HK.hist_batched_pallas(
+        x, w, wy, bins=EPS_Q)).lower(x, w, w).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("c, bound", [(512, 256 * 2 ** 20), (EPS_C, GiB)])
+def test_stuck_round_point_match_fits_at_epsilon_width(one_chip, c, bound):
+    """The quarantine's distinct count over a stuck round's k·c coreset
+    rows of 2,000 features.  Its temporaries, the [P, P] mask, stay under
+    256 MB at k·c = 8,192 (≈ 67 MB when this bound was set) and under
+    1 GiB at the cell's 16,384 (≈ 403 MB): the [P, P, F] compare is
+    fused, never held (it would be 134 GB and 537 GB)."""
+    from repro.core import classify
+    P = 16 * c
+    pts = _spec((P, EPS_F), jnp.float32, one_chip)
+    valid = _spec((P,), jnp.bool_, one_chip)
+    compiled = jax.jit(classify.distinct_count_masked).lower(
+        pts, valid).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < bound
+
+
+def test_tree_engine_compiles_at_epsilon_width(one_chip, monkeypatch):
+    """The cell's whole program: B = 1 task of m = 2^16 rows of 2,000
+    features on 256 bins, 16 parties with 1,024-row coresets, histogram
+    mode, the kernel branch steered on.  Temporaries stay under 5 GiB
+    (≈ 3.49 GB when this bound was set; 3.36 GB at 512-row coresets),
+    beside the 0.52 GB of arguments."""
+    monkeypatch.setattr(hist_ops, "_on_tpu", lambda: True)
+    cls = weak.make_class("tree", num_features=EPS_F, tree_depth=2,
+                          tree_bins=EPS_Q, tree_comm_mode="histogram")
+    cfg = BoostConfig(k=16, coreset_size=EPS_C, domain_size=1 << 20,
+                      opt_budget=256, deterministic_coreset=False)
+    m = 1 << 16
+    args = _engine_args(cfg, 1, m, EPS_F, one_chip)
+    compiled = batched._classify_batched_jit.lower(
+        *args, cfg, cls, cfg.num_rounds(m)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 5 * GiB
