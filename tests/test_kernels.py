@@ -5,8 +5,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import weights
 from repro.kernels.flash_attention import ops as flash_ops
 from repro.kernels.flash_attention.ref import flash_attention_ref
+from repro.kernels.histogram import kernel as hist_kernel
 from repro.kernels.histogram import ops as hist_ops
 from repro.kernels.histogram.ref import best_splits_ref, node_histograms_ref
 from repro.kernels.mw_update import ops as mw_ops
@@ -216,6 +218,76 @@ def test_histogram_kernel_batched_bitwise_parity(B, c, F, N, bins):
         for g, o in zip(got, one):
             np.testing.assert_array_equal(np.asarray(g[b]),
                                           np.asarray(o))
+
+
+# The kernel contracts its bfloat16 one-hot tile in one MXU pass: each
+# float32 weight enters as three bfloat16 pieces that sum back to it
+# exactly, so every histogram cell stays the exact sum.
+def _split_cases(kind, rng):
+    n = 4096
+    if kind == "positive":
+        return rng.uniform(0.0, 1.0, n).astype(np.float32)
+    if kind == "negative":
+        return -rng.uniform(0.0, 1.0, n).astype(np.float32)
+    if kind == "zero":
+        return np.zeros(n, np.float32)
+    if kind == "signed_wide_range":
+        mag = rng.uniform(0.5, 1.0, n) * 2.0 ** rng.integers(-40, 40, n)
+        return (mag * rng.choice([-1.0, 1.0], n)).astype(np.float32)
+    # kind == "grid_24_bits": k · 2^-23 with k in [2^23, 2^24), k odd:
+    # on the ERM weights' 2^-23 grid, all 24 significand bits set
+    k = rng.integers(2 ** 22, 2 ** 23, n) * 2 + 1
+    return (k * weights.ERM_WEIGHT_GRID * rng.choice([-1.0, 1.0], n)
+            ).astype(np.float32)
+
+
+@pytest.mark.parametrize("kind", ["positive", "negative", "zero",
+                                  "signed_wide_range", "grid_24_bits"])
+def test_bf16x3_split_puts_back_every_float32_bit(kind):
+    w = _split_cases(kind, np.random.default_rng(len(kind)))
+    hi, mid, lo = hist_kernel.split_bf16x3(jnp.asarray(w))
+    assert hi.dtype == mid.dtype == lo.dtype == jnp.bfloat16
+    pieces = [np.asarray(p, np.float32) for p in (hi, mid, lo)]
+    np.testing.assert_array_equal(pieces[0] + pieces[1] + pieces[2], w)
+    # each piece holds at most 8 significant bits of what was left: the
+    # next piece is below the previous one's last bit
+    for a, b in zip(pieces, pieces[1:]):
+        assert np.all(np.abs(b) <= np.abs(a) * 2.0 ** -7)
+    if kind == "grid_24_bits":
+        # the last piece is 0 only where bits 9-16 of w are all 0
+        assert np.mean(pieces[2] != 0) > 0.9
+
+
+@pytest.mark.parametrize("k,c,F,N,bins", [
+    (4, 3, 7, 2, 32), (3, 5, 5, 4, 16), (2, 8, 9, 1, 64), (16, 2, 3, 2, 8),
+])
+def test_histogram_kernel_bitwise_parity_on_erm_weights(k, c, F, N, bins):
+    """The kernel's batch axis holds a task's k parties, each with c
+    coreset rows at the ERM weight ``weights.erm_weights`` gives its
+    random mixture share, routed to N nodes and signed by the label
+    (``HistogramTrees.erm_players``).  Small coresets give weights with
+    more than 16 significant bits, so the third bfloat16 piece is not
+    zero: a kernel that dropped it would not pass."""
+    rng = np.random.default_rng(k * 31 + c + F + N)
+    lo_used = False
+    for _ in range(3):
+        mix = rng.dirichlet(np.ones(k)).astype(np.float32)
+        pw = np.asarray(weights.erm_weights(jnp.asarray(mix), c))
+        x = ((rng.integers(0, bins, (k, c, F)) + 0.5) / bins
+             ).astype(np.float32)
+        node = rng.integers(0, N, (k, c))
+        w = np.where(node[:, None, :] == np.arange(N)[None, :, None],
+                     pw[:, None, None], 0.0).astype(np.float32)
+        wy = w * rng.choice(np.array([-1.0, 1.0], np.float32), (k, 1, c))
+        lo_used |= np.any(np.asarray(hist_kernel.split_bf16x3(
+            jnp.asarray(w))[2]) != 0)
+        x, w, wy = jnp.asarray(x), jnp.asarray(w), jnp.asarray(wy)
+        ref = node_histograms_ref(x, w, wy, bins)
+        got = hist_ops.node_histograms(x, w, wy, bins, interpret=True)
+        for g, r in zip(got, ref):
+            assert g.shape == (k, N, F, bins)
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(r))
+    assert lo_used
 
 
 def test_histogram_zero_weight_rows_and_out_of_range():

@@ -198,15 +198,53 @@ EPS_F, EPS_Q, EPS_C = 2000, 256, 1024
 @pytest.mark.parametrize("N", [1, 2])
 def test_histogram_kernel_compiles_at_epsilon_width(one_chip, N):
     """The 16 parties of one task at 2,000 features on 256 bins: the
-    feature-blocked regime, 250 blocks of 8 features a launch (one
+    feature-blocked regime, 125 blocks of 16 features a launch (one
     block held every feature at HIGGS's width)."""
-    assert HK.feature_block(EPS_F, EPS_Q) == (8, EPS_F)
+    assert HK.feature_block(EPS_F, EPS_Q) == (16, EPS_F)
+    assert HK.vmem_bytes(N, EPS_F, EPS_Q) < 16 * 2 ** 20
     c = 16 * EPS_C
     x = _spec((16, c, EPS_F), jnp.float32, one_chip)
     w = _spec((16, N, c), jnp.float32, one_chip)
     compiled = jax.jit(lambda x, w, wy: HK.hist_batched_pallas(
         x, w, wy, bins=EPS_Q)).lower(x, w, w).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _bench_module(relpath):
+    """A module of the chip benchmark, loaded from its file."""
+    import importlib.util
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "chip_bench", relpath)
+    spec = importlib.util.spec_from_file_location(
+        os.path.splitext(os.path.basename(path))[0], path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.mark.parametrize("N", [1, 2])
+def test_histogram_kernel_result_keeps_the_roofline_dims(one_chip, N):
+    """The benchmark's ``histogram_roofline`` reads a launch's units and
+    N from the kernel's custom call as the device trace names it:
+    ``hist_batched_pallas.<n> = f32[B, Fp/BF, 2N, BF·Qp]``.  Whatever
+    the body contracts, that result must keep its shape, or the
+    counted work moves."""
+    roofline = _bench_module("roofline/histogram.py")
+    trace_reduce = _bench_module("trace_reduce.py")
+    c = 16 * EPS_C
+    x = _spec((16, c, EPS_F), jnp.float32, one_chip)
+    w = _spec((16, N, c), jnp.float32, one_chip)
+    hlo = jax.jit(lambda x, w, wy: HK.hist_batched_pallas(
+        x, w, wy, bins=EPS_Q)).lower(x, w, w).compile().as_text()
+    calls = [line.strip() for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1
+    op = trace_reduce.op_name(calls[0])
+    assert trace_reduce.base_name(op) == roofline.TRACE_NAME
+    bf, fp = HK.feature_block(EPS_F, EPS_Q)
+    dims = trace_reduce.out_dims(calls[0])
+    assert dims == [16, fp // bf, 2 * N, bf * HK.padded_bins(EPS_Q)]
+    assert roofline.from_result_dims(dims) == (16, N)
 
 
 @pytest.mark.parametrize("c, bound", [(512, 256 * 2 ** 20), (EPS_C, GiB)])

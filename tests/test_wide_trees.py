@@ -6,7 +6,7 @@ with parties, coresets and rows cut so that each test takes seconds.
   rounds, quarantine and ledger, bit for bit;
 * the benchmark's plain reference reads the engine's round count and
   ledger exactly;
-* the Pallas kernel, interpreted, at 250 feature blocks equals the
+* the Pallas kernel, interpreted, at 125 feature blocks equals the
   jnp histograms bit for bit on dyadic weights.
 """
 
@@ -103,13 +103,13 @@ def test_reference_reads_the_engine_round_count_and_ledger(wide):
 
 
 def test_feature_block_at_epsilon_width():
-    assert K.feature_block(F, Q) == (8, 2000)
+    assert K.feature_block(F, Q) == (16, 2000)
     assert K.vmem_bytes(2, F, Q) < 16 * 2 ** 20
 
 
 def test_interpreted_kernel_equals_the_jnp_histograms_at_2000_features():
-    """One unit, c = 256 points, N = 2 nodes: 250 feature blocks of
-    8 × 256 bins.  Weights on a dyadic grid, so every cell is an exact
+    """One unit, c = 256 points, N = 2 nodes: 125 feature blocks of
+    16 × 256 bins.  Weights on a dyadic grid, so every cell is an exact
     float32 sum and the two must agree bit for bit."""
     rng = np.random.default_rng(3)
     c, N = 256, 2
